@@ -134,9 +134,9 @@ def _fused_point(hot_name: str, S: int, npps: int) -> dict:
     n_slots = floor if hot_name == "small" else n_pages
     geom = TieredKV(n_pages, n_slots, PS, HKV, DH)
     cold = {"k": jax.random.normal(jax.random.PRNGKey(0),
-                                   (n_pages, PS, HKV, DH), jnp.float32),
+                                   (n_pages, HKV, PS, DH), jnp.float32),
             "v": jax.random.normal(jax.random.PRNGKey(1),
-                                   (n_pages, PS, HKV, DH), jnp.float32)}
+                                   (n_pages, HKV, PS, DH), jnp.float32)}
     pt = linear_page_table(S, npps)
     q = jax.random.normal(jax.random.PRNGKey(2), (S, 1, HQ, DH), jnp.float32)
     lengths = jnp.full((S,), npps * PS - 3, jnp.int32)
@@ -189,9 +189,9 @@ def _fused_point(hot_name: str, S: int, npps: int) -> dict:
 
 def run() -> tuple[list[dict], dict]:
     cold = {"k": jax.random.normal(jax.random.PRNGKey(0),
-                                   (N_PAGES, PS, HKV, DH), jnp.float32),
+                                   (N_PAGES, HKV, PS, DH), jnp.float32),
             "v": jax.random.normal(jax.random.PRNGKey(1),
-                                   (N_PAGES, PS, HKV, DH), jnp.float32)}
+                                   (N_PAGES, HKV, PS, DH), jnp.float32)}
     pt = linear_page_table(B, NPPS)
     q = jax.random.normal(jax.random.PRNGKey(2), (B, 1, HQ, DH), jnp.float32)
     lengths = jnp.full((B,), NPPS * PS - 3, jnp.int32)

@@ -56,9 +56,7 @@ def save_checkpoint(directory: str, step: int, tree, extras: dict | None = None,
                       "dtype": str(arr.dtype)})
     manifest = {
         "step": step,
-        "treedef": jax.tree_util.tree_structure(tree).serialize_using_proto().hex()
-        if hasattr(jax.tree_util.tree_structure(tree), "serialize_using_proto")
-        else None,
+        "treedef": treedef.serialize_using_proto().hex(),
         "n_leaves": len(leaves),
         "index": index,
         "extras": extras or {},

@@ -11,6 +11,15 @@ from .kernel import gather_pages_async_fwd, gather_pages_fwd
 from .ref import gather_pages_ref
 
 
+def _page_view(pool: jax.Array) -> jax.Array:
+    """``[n_pages, ...page]`` -> ``[n_pages, rows, lanes]``: the page's
+    trailing dim over everything else (a flat page is one row), so the
+    kernels' page block spans both trailing dims whole."""
+    if pool.ndim == 2:
+        return pool[:, None, :]
+    return pool.reshape(pool.shape[0], -1, pool.shape[-1])
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "use_kernel"))
 def gather_pages(pool: jax.Array, indices: jax.Array, *,
                  interpret: bool | None = None,
@@ -28,8 +37,7 @@ def gather_pages(pool: jax.Array, indices: jax.Array, *,
         return gather_pages_ref(pool.reshape(pool.shape[0], -1),
                                 indices).reshape((indices.shape[0],)
                                                  + pool.shape[1:])
-    flat = pool.reshape(pool.shape[0], -1)
-    out = gather_pages_fwd(flat, indices.astype(jnp.int32),
+    out = gather_pages_fwd(_page_view(pool), indices.astype(jnp.int32),
                            interpret=interpret)
     return out.reshape((indices.shape[0],) + pool.shape[1:])
 
@@ -53,7 +61,7 @@ def gather_pages_async(pool: jax.Array, indices: jax.Array, *,
         return gather_pages_ref(pool.reshape(pool.shape[0], -1),
                                 indices).reshape((indices.shape[0],)
                                                  + pool.shape[1:])
-    flat = pool.reshape(pool.shape[0], -1)
-    out = gather_pages_async_fwd(flat, indices.astype(jnp.int32),
+    out = gather_pages_async_fwd(_page_view(pool),
+                                 indices.astype(jnp.int32),
                                  interpret=interpret)
     return out.reshape((indices.shape[0],) + pool.shape[1:])

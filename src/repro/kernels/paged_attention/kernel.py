@@ -1,12 +1,16 @@
 """Pallas TPU paged-attention decode: one query token vs a paged KV pool.
 
-vLLM-style paged KV adapted to TPU: KV lives in a page pool
-[n_pages, page_size, Hkv, dh]; each sequence's logical context is a
-page_table row. The kernel fuses Leap's data path with the consumer: the
-page_table is a scalar-prefetch operand, so each (batch, kv-head, page) grid
-step DMAs exactly the page the table names — gather and attention in one
-pass, no [B, T, ...] contiguous cache ever materializes (that contiguous
-copy is the "block layer" overhead this kernel deletes).
+vLLM-style paged KV adapted to TPU: KV lives in a head-major page pool
+[n_pages, Hkv, page_size, dh]; each sequence's logical context is a
+page_table row. Head-major keeps every (page, kv-head) tile's last two dims
+``(page_size, dh)``, which is what the TPU tiling admits: the DMA'd block
+is a whole ``(page_size, dh)`` tile (a multiple of 16 tokens for bf16),
+never a one-row slice of a small ``Hkv`` axis. The kernel fuses Leap's
+data path with the consumer: the page_table is a scalar-prefetch operand,
+so each (batch, kv-head, page) grid step DMAs exactly the page the table
+names — gather and attention in one pass, no [B, T, ...] contiguous cache
+ever materializes (that contiguous copy is the "block layer" overhead this
+kernel deletes).
 
 Online softmax state (m, l, acc) for the G grouped q-heads lives in VMEM
 scratch across the page sweep (pages innermost). Padded/unused trailing
@@ -16,18 +20,18 @@ way — the DMA is clamped onto a real page so it stays well-formed, but the
 masked scores guarantee those bytes never reach the output (no silent
 garbage reads from a poisoned table).
 
-VMEM per step: k/v page tiles 2 x page_size x dh x 4 B (+ q tile G x dh) —
-page_size 64, dh 128 ≈ 64 KB: DMA-latency-bound, exactly the regime where
-prefetch-ahead (issuing the next page's DMA early) pays, mirroring the
-paper's timeliness axis.
+VMEM per step: k/v page tiles 2 x page_size x dh x 2 B (bf16, + q tile
+G x dh) — page_size 64, dh 128 ≈ 32 KB: DMA-latency-bound, exactly the
+regime where prefetch-ahead (issuing the next page's DMA early) pays,
+mirroring the paper's timeliness axis.
 
 Three entry points share one per-page online-softmax update
 (:func:`_attend_page` — identical op sequence, which is what keeps their
 outputs **bit-identical** on the same bytes):
 
-* :func:`paged_attention_fwd` — flat pool ``[n_pages, page, Hkv, dh]``.
+* :func:`paged_attention_fwd` — flat pool ``[n_pages, Hkv, page, dh]``.
 * :func:`paged_attention_hot_slots_fwd` — the tiered hot tier
-  ``[S, n_slots, page, Hkv, dh]`` read *in place* through a per-stream
+  ``[S, n_slots, Hkv, page, dh]`` read *in place* through a per-stream
   slot table: the BlockSpec index map composes the ``[S, npps] -> slot``
   indirection (stream s, slot ``slot_table[s, j]``) so the demand sweep
   lands pages and attention consumes them with **no stacked
@@ -95,8 +99,8 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32) * sm_scale       # [G, dh]
-    k = k_ref[0, :, 0].astype(jnp.float32)               # [page_size, dh]
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                  # [page_size, dh]
+    v = v_ref[0, 0].astype(jnp.float32)
     pt = pt_ref[b * n_pages_per_seq + j]
     mask = _page_mask((q.shape[0], page_size), j, page_size, len_ref[b],
                       (pt >= 0) & (pt < n_pages))
@@ -113,14 +117,14 @@ def paged_attention_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                         page_table: jax.Array, lengths: jax.Array, *,
                         sm_scale: float | None = None,
                         interpret: bool = True) -> jax.Array:
-    """q [B,Hkv,G,dh]; pools [n_pages,page_size,Hkv,dh];
+    """q [B,Hkv,G,dh]; pools [n_pages,Hkv,page_size,dh];
     page_table [B,n_pages_per_seq] int32; lengths [B] int32 -> [B,Hkv,G,dh].
 
     Invalid table entries (< 0 or >= n_pages) are masked out of the
     softmax; the in-range DMA clamp only keeps the access well-formed.
     """
     B, Hkv, G, dh = q.shape
-    n_pages, page_size = k_pool.shape[0], k_pool.shape[1]
+    n_pages, page_size = k_pool.shape[0], k_pool.shape[2]
     npps = page_table.shape[1]
     pt_flat = page_table.reshape(-1)          # raw: the body masks invalid
 
@@ -128,7 +132,7 @@ def paged_attention_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         return (b, h, 0, 0)
 
     def kv_map(b, h, j, pt, ln):
-        return (jnp.clip(pt[b * npps + j], 0, n_pages - 1), 0, h, 0)
+        return (jnp.clip(pt[b * npps + j], 0, n_pages - 1), h, 0, 0)
 
     kernel = functools.partial(
         _paged_kernel, sm_scale=sm_scale or 1.0 / (dh ** 0.5),
@@ -139,8 +143,8 @@ def paged_attention_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         grid=(B, Hkv, npps),
         in_specs=[
             pl.BlockSpec((1, 1, G, dh), q_map),
-            pl.BlockSpec((1, page_size, 1, dh), kv_map),
-            pl.BlockSpec((1, page_size, 1, dh), kv_map),
+            pl.BlockSpec((1, 1, page_size, dh), kv_map),
+            pl.BlockSpec((1, 1, page_size, dh), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, G, dh), q_map),
         scratch_shapes=[
@@ -174,8 +178,8 @@ def _hot_slots_kernel(st_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32) * sm_scale       # [G, dh]
-    k = k_ref[0, 0, :, 0].astype(jnp.float32)            # [page_size, dh]
-    v = v_ref[0, 0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0, 0].astype(jnp.float32)               # [page_size, dh]
+    v = v_ref[0, 0, 0].astype(jnp.float32)
     slot = st_ref[s * n_pages_per_seq + j]
     mask = _page_mask((q.shape[0], page_size), j, page_size, len_ref[s],
                       (slot >= 0) & (slot < n_slots))
@@ -193,18 +197,18 @@ def paged_attention_hot_slots_fwd(q: jax.Array, k_hot: jax.Array,
                                   lengths: jax.Array, *,
                                   sm_scale: float | None = None,
                                   interpret: bool = True) -> jax.Array:
-    """q [S,Hkv,G,dh]; hot pools [S,n_slots,page_size,Hkv,dh];
+    """q [S,Hkv,G,dh]; hot pools [S,n_slots,Hkv,page_size,dh];
     slot_table [S,npps] int32 *per-stream* slot ids; lengths [S] int32
     -> [S,Hkv,G,dh].
 
     The BlockSpec index map composes the slot indirection — grid step
-    (s, h, j) DMAs hot tile ``[s, slot_table[s, j], :, h, :]`` straight out
+    (s, h, j) DMAs hot tile ``[s, slot_table[s, j], h]`` straight out
     of the stacked per-stream hot pool, so no flattened ``[S*n_slots, ...]``
     pool is ever materialized. Non-resident entries (slot < 0, or past the
     slot count) are masked out of the softmax, never silently read.
     """
     S, Hkv, G, dh = q.shape
-    n_slots, page_size = k_hot.shape[1], k_hot.shape[2]
+    n_slots, page_size = k_hot.shape[1], k_hot.shape[3]
     npps = slot_table.shape[1]
     st_flat = slot_table.reshape(-1)          # raw: the body masks invalid
 
@@ -212,7 +216,7 @@ def paged_attention_hot_slots_fwd(q: jax.Array, k_hot: jax.Array,
         return (s, h, 0, 0)
 
     def kv_map(s, h, j, st, ln):
-        return (s, jnp.clip(st[s * npps + j], 0, n_slots - 1), 0, h, 0)
+        return (s, jnp.clip(st[s * npps + j], 0, n_slots - 1), h, 0, 0)
 
     kernel = functools.partial(
         _hot_slots_kernel, sm_scale=sm_scale or 1.0 / (dh ** 0.5),
@@ -223,8 +227,8 @@ def paged_attention_hot_slots_fwd(q: jax.Array, k_hot: jax.Array,
         grid=(S, Hkv, npps),
         in_specs=[
             pl.BlockSpec((1, 1, G, dh), q_map),
-            pl.BlockSpec((1, 1, page_size, 1, dh), kv_map),
-            pl.BlockSpec((1, 1, page_size, 1, dh), kv_map),
+            pl.BlockSpec((1, 1, 1, page_size, dh), kv_map),
+            pl.BlockSpec((1, 1, 1, page_size, dh), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, G, dh), q_map),
         scratch_shapes=[
@@ -262,7 +266,7 @@ def _hot_slots_async_kernel(st_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     def dma(hbm, scr, buf, j, which):
         slot = jnp.clip(st_ref[s * npps + j], 0, n_slots - 1)
-        return pltpu.make_async_copy(hbm.at[s, slot, :, h],
+        return pltpu.make_async_copy(hbm.at[s, slot, h],
                                      scr.at[buf], sem_ref.at[buf, which])
 
     dma(k_ref, k_scr, 0, 0, 0).start()       # warm-up: issue page 0
@@ -307,7 +311,7 @@ def paged_attention_hot_slots_async_fwd(q: jax.Array, k_hot: jax.Array,
     tiles in flight (k+v, double-buffered) + the q/o blocks.
     """
     S, Hkv, G, dh = q.shape
-    n_slots, page_size = k_hot.shape[1], k_hot.shape[2]
+    n_slots, page_size = k_hot.shape[1], k_hot.shape[3]
     npps = slot_table.shape[1]
     st_flat = slot_table.reshape(-1)
 
@@ -323,8 +327,8 @@ def paged_attention_hot_slots_async_fwd(q: jax.Array, k_hot: jax.Array,
         grid=(S, Hkv),
         in_specs=[
             pl.BlockSpec((1, 1, G, dh), q_map),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, 1, G, dh), q_map),
         scratch_shapes=[

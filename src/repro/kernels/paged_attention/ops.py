@@ -18,7 +18,8 @@ from .ref import paged_attention_hot_slots_ref, paged_attention_ref
 @functools.partial(jax.jit, static_argnames=("interpret", "use_kernel"))
 def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
                     interpret: bool | None = None, use_kernel: bool = True):
-    """q [B,1,Hq,dh] (model layout) -> [B,1,Hq,dh].
+    """q [B,1,Hq,dh] (model layout) vs head-major pools
+    [n_pages,Hkv,page,dh] -> [B,1,Hq,dh].
 
     Invalid page-table entries (< 0 or >= n_pages) are masked out of the
     softmax by both the kernel and the ref — a poisoned table never
@@ -27,7 +28,7 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     B, one, Hq, dh = q.shape
-    Hkv = k_pool.shape[2]
+    Hkv = k_pool.shape[1]
     G = Hq // Hkv
     qg = q[:, 0].reshape(B, Hkv, G, dh)
     fn = paged_attention_fwd if use_kernel else paged_attention_ref
@@ -44,7 +45,7 @@ def paged_attention_hot_slots(q, k_hot, v_hot, slot_table, lengths, *,
                               use_kernel: bool = True,
                               async_copy: bool = False):
     """Fused hot-slot decode attention: q [S,1,Hq,dh] (model layout) vs the
-    tiered hot pools [S,n_slots,page,Hkv,dh] read in place through the
+    tiered hot pools [S,n_slots,Hkv,page,dh] read in place through the
     *per-stream* slot_table [S,npps] — no stacked [S*n_slots,...] pool.
 
     Entries < 0 or >= n_slots (non-resident / poisoned) are masked out of
@@ -55,7 +56,7 @@ def paged_attention_hot_slots(q, k_hot, v_hot, slot_table, lengths, *,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     S, one, Hq, dh = q.shape
-    Hkv = k_hot.shape[3]
+    Hkv = k_hot.shape[2]
     G = Hq // Hkv
     qg = q[:, 0].reshape(S, Hkv, G, dh)
     if use_kernel:

@@ -27,11 +27,11 @@ def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                         sm_scale: float | None = None) -> jax.Array:
     """Same contract as kernel.paged_attention_fwd."""
     B, Hkv, G, dh = q.shape
-    n_pages, page_size = k_pool.shape[0], k_pool.shape[1]
+    n_pages, page_size = k_pool.shape[0], k_pool.shape[2]
     valid = (page_table >= 0) & (page_table < n_pages)   # [B, npps]
     pt = jnp.clip(page_table, 0, n_pages - 1)
-    k = k_pool[pt]                                  # [B,npps,page,Hkv,dh]
-    v = v_pool[pt]
+    k = k_pool[pt].swapaxes(2, 3)                   # [B,npps,page,Hkv,dh]
+    v = v_pool[pt].swapaxes(2, 3)
     B_, npps = pt.shape
     T = npps * page_size
     k = k.reshape(B, T, Hkv, dh).astype(jnp.float32)
@@ -48,15 +48,17 @@ def paged_attention_hot_slots_ref(q: jax.Array, k_hot: jax.Array,
                                   sm_scale: float | None = None) -> jax.Array:
     """Same contract as kernel.paged_attention_hot_slots_fwd.
 
-    q [S,Hkv,G,dh]; hot pools [S,n_slots,page,Hkv,dh]; slot_table [S,npps]
+    q [S,Hkv,G,dh]; hot pools [S,n_slots,Hkv,page,dh]; slot_table [S,npps]
     per-stream slot ids (-1 or out-of-range = masked); lengths [S].
     """
     S, Hkv, G, dh = q.shape
-    n_slots, page_size = k_hot.shape[1], k_hot.shape[2]
+    n_slots, page_size = k_hot.shape[1], k_hot.shape[3]
     valid = (slot_table >= 0) & (slot_table < n_slots)   # [S, npps]
     st = jnp.clip(slot_table, 0, n_slots - 1)
-    k = jnp.take_along_axis(k_hot, st[:, :, None, None, None], axis=1)
-    v = jnp.take_along_axis(v_hot, st[:, :, None, None, None], axis=1)
+    k = jnp.take_along_axis(k_hot, st[:, :, None, None, None],
+                            axis=1).swapaxes(2, 3)
+    v = jnp.take_along_axis(v_hot, st[:, :, None, None, None],
+                            axis=1).swapaxes(2, 3)
     S_, npps = st.shape
     T = npps * page_size
     k = k.reshape(S, T, Hkv, dh).astype(jnp.float32)

@@ -18,12 +18,22 @@ parallel; on CPU CI the fabric devices come from
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with ``Auto`` axes. Every caller here places
+    arrays by ``PartitionSpec`` — ``shard_map`` specs, the pipeline, the
+    logical-axis sharding rules under ``jit`` — and lets GSPMD propagate the
+    rest, which ``Explicit`` axes (``make_mesh``'s default) refuse outside
+    a ``jax.set_mesh`` context."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_fabric_mesh(n_shards: int):
@@ -38,11 +48,11 @@ def make_fabric_mesh(n_shards: int):
             f"need {n_shards} devices for a {n_shards}-shard fabric mesh, "
             f"have {jax.device_count()} — on CPU set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n_shards}")
-    return jax.make_mesh((n_shards,), ("fabric",))
+    return make_mesh((n_shards,), ("fabric",))
 
 
 def make_host_mesh(model: int = 1):
     """Tiny mesh on whatever devices exist (tests / CPU examples)."""
     n = jax.device_count()
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

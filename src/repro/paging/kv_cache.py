@@ -1,9 +1,11 @@
 """Paged KV cache: pool + page table + append + attention.
 
-Layout per layer stack: ``k_pool/v_pool [n_pages, page_size, Hkv, dh]`` with
-the page dim shardable over the mesh — pages of a sequence's context live
-round-robin across chips, which *is* the disaggregated memory pool of the
-paper (each chip contributes "remote memory" for everyone else's sequences).
+Layout per layer stack: ``k_pool/v_pool [n_pages, Hkv, page_size, dh]``
+(head-major, so each page's per-head tile is a whole ``(page_size, dh)``
+TPU tile) with the page dim shardable over the mesh — pages of a
+sequence's context live round-robin across chips, which *is* the
+disaggregated memory pool of the paper (each chip contributes "remote
+memory" for everyone else's sequences).
 ``page_table [B, n_pages_per_seq]`` maps logical to physical pages.
 
 Two allocators:
@@ -27,16 +29,16 @@ from repro.kernels.paged_attention import paged_attention
 
 def init_paged_kv(n_layers: int, n_pages: int, page_size: int, n_kv_heads: int,
                   head_dim: int, dtype=jnp.bfloat16) -> dict:
-    """Zeroed KV pool: ``{"k","v"}`` each ``[L, n_pages, page, Hkv, dh]``
+    """Zeroed KV pool: ``{"k","v"}`` each ``[L, n_pages, Hkv, page, dh]``
     of ``dtype`` (default bf16). The page dim is the mesh-shardable
     disaggregated tier (see :func:`kv_pool_specs`)."""
-    sh = (n_layers, n_pages, page_size, n_kv_heads, head_dim)
+    sh = (n_layers, n_pages, n_kv_heads, page_size, head_dim)
     return {"k": jnp.zeros(sh, dtype), "v": jnp.zeros(sh, dtype)}
 
 
 def kv_pool_specs(n_layers: int) -> dict:
     """Logical axes: page dim sharded (the disaggregated tier)."""
-    ax = ("layers", "pages", None, "kv_heads_s", None)
+    ax = ("layers", "pages", "kv_heads_s", None, None)
     return {"k": ax, "v": ax}
 
 
@@ -69,17 +71,17 @@ def append_kv(pool: dict, layer: jax.Array, k_new: jax.Array, v_new: jax.Array,
     """Write one token's K/V for every sequence at position ``pos``.
 
     ``k_new``/``v_new`` are ``[B, Hkv, dh]`` (cast to the pool dtype); pool
-    leaves are ``[L, n_pages, page, Hkv, dh]``; ``layer``/``pos`` are scalar
+    leaves are ``[L, n_pages, Hkv, page, dh]``; ``layer``/``pos`` are scalar
     int32. Returns the updated pool dict (functional, jit/scan-safe).
     """
-    page_size = pool["k"].shape[2]
+    page_size = pool["k"].shape[3]
     B = k_new.shape[0]
     logical = pos // page_size
     offset = pos % page_size
     phys = page_table[jnp.arange(B), logical]            # [B]
 
     def write(buf, new):
-        return buf.at[layer, phys, offset].set(new.astype(buf.dtype))
+        return buf.at[layer, phys, :, offset].set(new.astype(buf.dtype))
 
     return {"k": write(pool["k"], k_new), "v": write(pool["v"], v_new)}
 

@@ -630,20 +630,19 @@ def cached_shard_map(key: tuple, make_fn, in_specs):
 
     The single implementation of the §7 wrap idiom (cold sharded over the
     ``fabric`` axis, every other input and all outputs replicated,
-    ``check_rep=False`` because the replication of the metadata scan is by
+    ``check_vma=False`` because the replication of the metadata scan is by
     construction, not provable) — the stream consume and the tiered sweep
     both build their mesh runners through it. ``key`` must start with the
     mesh and include a caller tag plus every static config the wrapped
     ``make_fn()`` closes over; entries live for the process, like jit's
     own executable cache.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if key not in _SHARD_MAP_CACHE:
-        _SHARD_MAP_CACHE[key] = jax.jit(shard_map(
+        _SHARD_MAP_CACHE[key] = jax.jit(jax.shard_map(
             make_fn(), mesh=key[0], in_specs=in_specs, out_specs=P(),
-            check_rep=False))
+            check_vma=False))
     return _SHARD_MAP_CACHE[key]
 
 

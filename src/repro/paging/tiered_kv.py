@@ -5,10 +5,10 @@ instead of a stand-alone page-stream simulator running beside the model, the
 KV pages that decode attention actually reads live in a two-tier hierarchy —
 
 * **cold tier**: the existing paged KV pool layer slice
-  (``{"k","v"}: [n_pages, page_size, Hkv, dh]``, the mesh-shardable
+  (``{"k","v"}: [n_pages, Hkv, page_size, dh]``, the mesh-shardable
   disaggregated side, :mod:`repro.paging.kv_cache`);
 * **hot tier**: a small HBM-resident pool of slots *per request stream*
-  (``{"k","v"}: [n_streams, n_slots, page_size, Hkv, dh]`` — the k and v
+  (``{"k","v"}: [n_streams, n_slots, Hkv, page_size, dh]`` — the k and v
   leaves of a slot always move together), managed by the per-stream Leap
   controller exactly like the kernel-space page cache of the paper.
 
@@ -90,7 +90,8 @@ class TieredKV:
                   :func:`tiered_min_slots` of the sweep length so every
                   swept page is still resident when attention reads it.
       page_size:  tokens per KV page.
-      n_kv_heads / head_dim: KV page payload shape.
+      n_kv_heads / head_dim: KV page payload shape (head-major
+                  ``[Hkv, page_size, dh]``).
       chunk:      demand pages per sweep step (the multi-page demand batch).
       pw_max / h_size / n_split: Leap controller knobs (see
                   :class:`repro.paging.prefetch_serving.PrefetchedStream`).
@@ -115,7 +116,7 @@ class TieredKV:
 
     @property
     def page_shape(self) -> tuple[int, int, int]:
-        return (self.page_size, self.n_kv_heads, self.head_dim)
+        return (self.n_kv_heads, self.page_size, self.head_dim)
 
 
 def tiered_min_slots(npps: int, geom: TieredKV) -> int:
@@ -137,7 +138,7 @@ def tiered_init(geom: TieredKV, n_streams: int, dtype=jnp.bfloat16) -> dict:
     Keys per stream: ``leap`` (controller), ``pool_meta``
     (:func:`repro.core.pool.pool_init`), ``ring``
     (:func:`repro.core.pool.ring_init`) and the hot payload
-    ``hot = {"k","v"}: [n_slots, page_size, Hkv, dh]`` of ``dtype``.
+    ``hot = {"k","v"}: [n_slots, Hkv, page_size, dh]`` of ``dtype``.
     """
     kv = jnp.zeros((geom.n_slots,) + geom.page_shape, dtype)
     one = {
@@ -423,7 +424,7 @@ def tiered_sweep(state: dict, cold: dict, page_rows: jax.Array,
 
     Args:
       state: stacked tiered state from :func:`tiered_init`.
-      cold:  ``{"k","v"}: [n_pages, page_size, Hkv, dh]`` cold tier (one
+      cold:  ``{"k","v"}: [n_pages, Hkv, page_size, dh]`` cold tier (one
              layer slice of the paged KV pool), in original page-id order.
       page_rows: ``int32[S, npps]`` physical page ids per stream (the
              page-table rows of the requests each stream serves; ``-1``
@@ -519,7 +520,7 @@ def tiered_slot_table_local(state: dict, page_rows: jax.Array
 
     Returns ``(slot_table int32[S, npps], all_resident bool)``:
     ``slot_table[s, j]`` indexes stream s's own hot pool
-    ``[n_slots, page, Hkv, dh]``, with ``-1`` for invalid page-table
+    ``[n_slots, Hkv, page, dh]``, with ``-1`` for invalid page-table
     entries **and** non-resident pages — the form the fused
     :func:`repro.kernels.paged_attention.paged_attention_hot_slots` kernel
     consumes directly (its residency mask folds the ``all_resident`` guard
@@ -587,7 +588,7 @@ def tiered_attention(q: jax.Array, state: dict, page_rows: jax.Array,
 
     * ``"ref"`` / ``"kernel"`` — the **unfused** stacked path: the
       per-stream hot pools are copied into one flattened
-      ``[S * n_slots, page, Hkv, dh]`` pool every call (a full hot-pool
+      ``[S * n_slots, Hkv, page, dh]`` pool every call (a full hot-pool
       materialization) and attention runs through the remapped global
       table — identical shapes and identical bytes as the flat-pool
       :func:`repro.paging.kv_cache.paged_decode_attention`.

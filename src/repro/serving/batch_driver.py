@@ -98,7 +98,7 @@ def serve_batch_tiered(cfg, state, args, B: int, prompt_len: int,
     pos_ids = jnp.arange(npps * ps)
     prefix = lambda x: jnp.where((pos_ids < prompt_len)[None, :, None, None],
                                  x, 0)
-    to_pages = lambda x: x.reshape(B * npps, ps, hkv, dh)
+    to_pages = lambda x: x.reshape(B * npps, ps, hkv, dh).swapaxes(1, 2)
     pool = {"k": pool["k"].at[0, pt_full.reshape(-1)].set(
                 to_pages(prefix(kd))),
             "v": pool["v"].at[0, pt_full.reshape(-1)].set(
@@ -159,8 +159,12 @@ def serve_batch_tiered(cfg, state, args, B: int, prompt_len: int,
                                         link_budget=args.link_budget,
                                         fabric=fabric, mesh=mesh)
             sp.sync = info
+        # one copy of the mesh-replicated hot tier for the Mosaic attention
+        # kernel, which XLA cannot partition
+        hot = (tstate if mesh is None
+               else jax.device_put(tstate, pool["k"].sharding))
         with reg.span("tiered_attention") as sp:
-            tiered, resident = tiered_attention(q, tstate, rows, lengths,
+            tiered, resident = tiered_attention(q, hot, rows, lengths,
                                                 attn_kernel=attn_mode)
             sp.sync = tiered
         flat = paged_decode_attention(

@@ -72,7 +72,9 @@ class ServeConfig:
     #: per-request length heterogeneity: request i draws prompt/gen
     #: uniformly from [ceil(len*(1-jitter)), len] (seeded). 0 = uniform.
     length_jitter: float = 0.0
-    page_size: int = 4
+    #: tokens per KV page; a multiple of 16 keeps each bf16 (page, dh)
+    #: tile whole under the TPU tiling the Pallas kernels DMA
+    page_size: int = 16
     prefill_chunk: int = 8        # prompt tokens per engine step per slot
     chunk: int = 4                # sweep demand pages per chunk step
     ring_size: int = 8
@@ -201,7 +203,7 @@ class ServingEngine:
         self._chunk_clock = 0
         self._n_chunks = -(-self.npps // c.chunk)
         self._inv_width = c.slots * max(c.prefill_chunk, 1)
-        self._finished: list[Request] = []
+        self.finished: list[Request] = []
 
     # -- device helpers ------------------------------------------------------
     def _write_tokens(self, req: Request, k, v, start: int) -> list[int]:
@@ -215,6 +217,15 @@ class ServingEngine:
         off = (start + jnp.arange(n, dtype=jnp.int32)) % ps
         self.pool = _scatter_tokens(self.pool, pg, off, k, v)
         return sorted(set(pages))
+
+    def attention_state(self) -> dict:
+        """The tiered state as the attention consumer reads it. With a
+        fabric mesh the sweep's shard_map leaves it replicated over the
+        mesh, and the attention kernel (Mosaic, which XLA cannot
+        partition) reads one copy beside the pool."""
+        if self.mesh is None:
+            return self.tstate
+        return jax.device_put(self.tstate, self.pool["k"].sharding)
 
     def _sweep_and_pin(self, t: int, decoding: list[Request]) -> None:
         S, npps = self.cfg.slots, self.npps
@@ -276,8 +287,9 @@ class ServingEngine:
             sp.sync = info
         mode = normalize_attn_kernel(self.cfg.attn_kernel)
         with self.reg.span("tiered_attention") as sp:
-            tiered, resident = tiered_attention(q, self.tstate, rows_j,
-                                                lengths_j, attn_kernel=mode)
+            tiered, resident = tiered_attention(q, self.attention_state(),
+                                                rows_j, lengths_j,
+                                                attn_kernel=mode)
             sp.sync = tiered
         flat = paged_decode_attention(q, self.pool, jnp.int32(0), rows_j,
                                       lengths_j,
@@ -310,6 +322,8 @@ class ServingEngine:
                 n = min(self.cfg.prefill_chunk,
                         req.prompt_len - req.prefilled)
                 k, v, tok = self.ex.prefill_chunk(req, n)
+                if tok is not None:
+                    req.tokens.append(tok)
                 pages = self._write_tokens(req, k, v, req.prefilled)
                 written.extend((req.slot, p) for p in pages)
                 req.advance_prefill(n, t)
@@ -324,6 +338,7 @@ class ServingEngine:
                 with self.reg.span("token_latency") as sp:
                     k, v, tok = self.ex.decode(req)
                     sp.sync = k
+                req.tokens.append(tok)
                 pages = self._write_tokens(req, k[None], v[None], pos)
                 written.extend((req.slot, p) for p in pages)
                 done = req.advance_decode(t)
@@ -369,7 +384,7 @@ class ServingEngine:
                                           self.dtype)
         self.sched.finish(req, t)
         self.ex.end(req)
-        self._finished.append(req)
+        self.finished.append(req)
         self.phases.append(RequestPhase("evict", req.req_id, t, t, slot))
 
     # -- run -----------------------------------------------------------------
@@ -417,8 +432,8 @@ class ServingEngine:
             "steps": steps,
             "wall_s": round(wall, 3),
             "tiered_equiv_ok": self.equiv_ok,
-            "requests_finished": len(self._finished),
-            "tokens_decoded": sum(r.decoded for r in self._finished),
+            "requests_finished": len(self.finished),
+            "tokens_decoded": sum(r.decoded for r in self.finished),
             "ttft_steps": rnd(ttfts.ladder()),
             "mean_ttft_steps": round(float(np.mean(ttfts.samples)), 3)
             if ttfts.samples else float("nan"),
@@ -457,19 +472,45 @@ def _roundtrip_pages(pool: dict, pages) -> dict:
 
 @jax.jit
 def _scatter_tokens(pool: dict, pages, offs, k_new, v_new) -> dict:
-    """Write ``n`` tokens' K/V at ``(pages[j], offs[j])`` of layer 0."""
+    """Write ``n`` tokens' K/V ``[n, Hkv, dh]`` at ``(pages[j], offs[j])``
+    of layer 0 (head-major pool ``[1, n_pages, Hkv, page, dh]``)."""
     def wr(buf, new):
-        return buf.at[0, pages, offs].set(new.astype(buf.dtype))
+        return buf.at[0, pages, :, offs].set(new.astype(buf.dtype))
 
     return {"k": wr(pool["k"], k_new), "v": wr(pool["v"], v_new)}
+
+
+def gate_failures(report: dict, requests: int) -> list[str]:
+    """The engine's own run gates over a :meth:`ServingEngine.run` report:
+    the §6.4 flat/tiered pin, every request finished, no page leak, page
+    conservation, and (when traced) the §8.2 event totals. Empty = pass."""
+    out = []
+    if not report["tiered_equiv_ok"]:
+        out.append("tiered/flat decode attention mismatch under continuous "
+                   f"batching (first bad step "
+                   f"{report.get('tiered_first_bad_step')})")
+    if report["requests_finished"] != requests:
+        out.append(f"{report['requests_finished']}/{requests} requests "
+                   "finished")
+    if report["alloc_in_use_end"] != 0:
+        out.append(f"page leak: {report['alloc_in_use_end']} pages still "
+                   "allocated after drain")
+    if report["pages_allocated"] != report["pages_recycled"]:
+        out.append("page conservation violated: "
+                   f"{report['pages_allocated']} allocated vs "
+                   f"{report['pages_recycled']} recycled")
+    if not report.get("trace_totals_ok", True):
+        out.append("trace event totals diverge from pool counters (decode "
+                   "contract violation, DESIGN.md §8.2)")
+    return out
 
 
 def serve_continuous(config: ServeConfig, executor=None, arch: str = None,
                      smoke: bool = True) -> dict:
     """Build an executor (real model or synthetic), run the engine once.
 
-    ``arch=None`` (or an encdec/unsupported family) uses the synthetic
-    executor — real scheduling, paging and pins over PRNG K/V bytes.
+    ``arch=None`` uses the synthetic executor — real scheduling, paging and
+    pins over PRNG K/V bytes.
     """
     if executor is None:
         executor = build_executor(arch, smoke=smoke, seed=config.seed)
@@ -477,15 +518,13 @@ def serve_continuous(config: ServeConfig, executor=None, arch: str = None,
 
 
 def build_executor(arch: str | None, smoke: bool = True, seed: int = 0):
-    """The real :class:`ModelExecutor` for ``arch``, falling back to
-    :class:`SyntheticExecutor` for cache-incompatible families."""
+    """The real :class:`ModelExecutor` for ``arch``; ``arch=None`` is the
+    :class:`SyntheticExecutor`. Families the engine cannot serve (encdec)
+    raise — never a silent PRNG stand-in under a model's name."""
     from .executor import ModelExecutor, SyntheticExecutor
 
     if arch is None:
         return SyntheticExecutor(n_kv_heads=2, head_dim=8, seed=seed)
     from repro import configs as cfglib
     cfg = cfglib.get_smoke_config(arch) if smoke else cfglib.get_config(arch)
-    if cfg.family == "encdec":
-        return SyntheticExecutor(cfg.n_kv_heads, cfg.head_dim, cfg.dtype,
-                                 seed=seed)
     return ModelExecutor(cfg, seed=seed)

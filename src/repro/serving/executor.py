@@ -30,6 +30,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.model import build_model
 
@@ -93,8 +94,10 @@ class ModelExecutor:
                              "driver")
         self.cfg = cfg
         self.model = build_model(cfg)
-        self.params, _ = self.model.init_params(jax.random.PRNGKey(seed))
-        self._decode = jax.jit(self.model.decode_step)
+        self.params = jax.jit(lambda k: self.model.init_params(k)[0])(
+            jax.random.PRNGKey(seed))
+        self._decode = jax.jit(self._decode_and_kv)
+        self._prefill = jax.jit(self.model.prefill, static_argnums=2)
         self._key = jax.random.PRNGKey(seed + 1)
         self._states: dict[int, dict] = {}
         self._prompts: dict[int, jax.Array] = {}
@@ -136,36 +139,43 @@ class ModelExecutor:
         self._last_tok.pop(req.req_id, None)
         self.last_logits.pop(req.req_id, None)
 
-    def _kv_written(self, req: Request, state, pos: int):
-        """The K/V bytes ``decode_step`` just wrote at cache position
-        ``pos`` — ``[Hkv, dh]`` each — from the first attention stack of
-        the scan period. Cache-free families (pure mamba/xlstm) mirror
-        synthetic bytes so the data path stays end-to-end real."""
+    def _decode_and_kv(self, params, tok, state):
+        """``decode_step`` plus the K/V bytes it just wrote at the input
+        token's cache position — ``[Hkv, dh]`` each — from the first
+        attention stack of the scan period (``None`` for cache-free
+        families). One jitted program: no per-position host work."""
+        pos = state["pos"]
+        logits, state = self.model.decode_step(params, tok, state)
         if self._attn_period is None:
-            k, v = self._synth._kv(req, pos, 1)
-            return k[0], v[0]
+            return logits, state, None, None
         blk = state["blocks"][self._attn_period]
-        return blk["k"][0, 0, pos], blk["v"][0, 0, pos]
+        k, v = (jax.lax.dynamic_index_in_dim(blk[n][0, 0], pos,
+                                             keepdims=False)
+                for n in ("k", "v"))
+        return logits, state, k, v
 
-    def _feed(self, req: Request, token: int | jax.Array):
+    def _feed(self, req: Request, token: int):
         """One ``decode_step``: returns ``(logits [V], k, v)`` where k/v
-        are the bytes written for the *input* token at its position."""
-        state = self._states[req.req_id]
-        pos = int(state["pos"])
+        are the bytes written for the *input* token at its position.
+        Cache-free families (pure mamba/xlstm) mirror synthetic bytes so
+        the data path stays end-to-end real."""
         tok = jnp.asarray([token], jnp.int32)
-        logits, state = self._decode(self.params, tok, state)
+        logits, state, k, v = self._decode(self.params, tok,
+                                           self._states[req.req_id])
         self._states[req.req_id] = state
-        k, v = self._kv_written(req, state, pos)
+        if k is None:
+            k, v = self._synth._kv(req, int(state["pos"]) - 1, 1)
+            k, v = k[0], v[0]
         return logits[0], k, v
 
     def prefill_chunk(self, req: Request, n: int):
         """Consume ``n`` prompt tokens; K/V ``[n, Hkv, dh]``; the first
         output token when the prompt is exhausted."""
-        prompt = self.prompt_tokens(req)
+        prompt = np.asarray(self.prompt_tokens(req))
         ks, vs = [], []
         logits = None
         for j in range(req.prefilled, req.prefilled + n):
-            logits, k, v = self._feed(req, prompt[j])
+            logits, k, v = self._feed(req, int(prompt[j]))
             ks.append(k)
             vs.append(v)
         tok = None
@@ -187,5 +197,5 @@ class ModelExecutor:
         """Reference: ``model.prefill`` over the same prompt in one shot
         (the chunked-prefill equivalence oracle; [V] float32)."""
         batch = {"tokens": self.prompt_tokens(req)[None]}
-        logits, _ = self.model.prefill(self.params, batch, req.max_len)
+        logits, _ = self._prefill(self.params, batch, req.max_len)
         return logits[0]

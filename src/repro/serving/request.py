@@ -60,6 +60,7 @@ class Request:
     prefilled: int = 0          # prompt tokens consumed so far
     decoded: int = 0            # output tokens emitted so far
     pages: list[int] = dataclasses.field(default_factory=list)
+    tokens: list[int] = dataclasses.field(default_factory=list)  # emitted
     admit_step: int = -1
     first_token_step: int = -1
     finish_step: int = -1

@@ -119,8 +119,8 @@ class TestPagedAttention:
         npages = npps * B + 4
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
         q = jax.random.normal(ks[0], (B, 1, Hq, dh), dtype)
-        kp = jax.random.normal(ks[1], (npages, ps, Hkv, dh), dtype)
-        vp = jax.random.normal(ks[2], (npages, ps, Hkv, dh), dtype)
+        kp = jax.random.normal(ks[1], (npages, Hkv, ps, dh), dtype)
+        vp = jax.random.normal(ks[2], (npages, Hkv, ps, dh), dtype)
         pt = jax.random.randint(ks[3], (B, npps), 0, npages)
         ln = jnp.asarray(np.random.default_rng(0).integers(1, ps * npps + 1,
                                                            B), jnp.int32)
@@ -138,8 +138,8 @@ class TestPagedAttention:
         q = jax.random.normal(ks[0], (B, 1, Hq, dh))
         kd = jax.random.normal(ks[1], (B, ps * npps, Hkv, dh))
         vd = jax.random.normal(ks[2], (B, ps * npps, Hkv, dh))
-        kp = kd.reshape(B * npps, ps, Hkv, dh)
-        vp = vd.reshape(B * npps, ps, Hkv, dh)
+        kp = kd.reshape(B * npps, ps, Hkv, dh).swapaxes(1, 2)
+        vp = vd.reshape(B * npps, ps, Hkv, dh).swapaxes(1, 2)
         pt = jnp.arange(B * npps, dtype=jnp.int32).reshape(B, npps)
         ln = jnp.array([20, 32], jnp.int32)
         a = paged_attention(q, kp, vp, pt, ln, interpret=True)
@@ -155,8 +155,8 @@ class TestPagedAttention:
         npages = 8
         ks = jax.random.split(jax.random.PRNGKey(3), 4)
         q = jax.random.normal(ks[0], (B, 1, Hq, dh))
-        kp = jax.random.normal(ks[1], (npages, ps, Hkv, dh))
-        vp = jax.random.normal(ks[2], (npages, ps, Hkv, dh))
+        kp = jax.random.normal(ks[1], (npages, Hkv, ps, dh))
+        vp = jax.random.normal(ks[2], (npages, Hkv, ps, dh))
         ln = jnp.full((B,), ps * npps, jnp.int32)   # poison inside lengths
         pt = jnp.asarray([[0, 1, 2, 3], [4, 5, 6, 7]], jnp.int32)
         pois = pt.at[0, 1].set(-1).at[1, 2].set(npages + 50)
@@ -192,8 +192,8 @@ class TestPagedAttentionHotSlots:
     def _mk(self, S, n_slots, ps, Hkv, Hq, dh, npps, dtype, seed=0):
         ks = jax.random.split(jax.random.PRNGKey(seed), 4)
         q = jax.random.normal(ks[0], (S, 1, Hq, dh), dtype)
-        kh = jax.random.normal(ks[1], (S, n_slots, ps, Hkv, dh), dtype)
-        vh = jax.random.normal(ks[2], (S, n_slots, ps, Hkv, dh), dtype)
+        kh = jax.random.normal(ks[1], (S, n_slots, Hkv, ps, dh), dtype)
+        vh = jax.random.normal(ks[2], (S, n_slots, Hkv, ps, dh), dtype)
         st = jax.random.randint(ks[3], (S, npps), 0, n_slots, jnp.int32)
         ln = jnp.asarray(np.random.default_rng(seed).integers(
             1, ps * npps + 1, S), jnp.int32)

@@ -163,7 +163,7 @@ class TestDecodePinsCounters:
         geom = dataclasses.replace(
             geom, n_slots=tiered_min_slots(npps, geom))
         k = jnp.arange(B * npps * ps * 2 * 8,
-                       dtype=jnp.float32).reshape(B * npps, ps, 2, 8)
+                       dtype=jnp.float32).reshape(B * npps, 2, ps, 8)
         cold = {"k": k, "v": k + 1.0}
         pt = linear_page_table(B, npps)
         st = tiered_init(geom, B, jnp.float32)

@@ -16,8 +16,9 @@ SCRIPT = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
     from repro.distributed.pipeline import pipeline_forward
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = make_mesh((4,), ("pod",))
     S, D, B = 4, 8, 8
     ks = jax.random.split(jax.random.PRNGKey(0), S)
     params = jnp.stack([jax.random.normal(k, (D, D)) / np.sqrt(D) for k in ks])

@@ -19,7 +19,9 @@ Three layers of coverage for :mod:`repro.serving` (DESIGN.md §10):
 
 import functools
 import json
+from pathlib import Path
 
+import jax
 import numpy as np
 import pytest
 
@@ -36,6 +38,7 @@ from repro.obs.trace import RequestPhase
 from repro.paging.kv_cache import PageAllocator
 from repro.serving import (AdmissionQueue, Request, ServeConfig,
                            ServingEngine, SlotScheduler, SyntheticExecutor)
+from repro.serving.engine import build_executor, gate_failures
 from repro.serving.request import DECODE, FINISHED, PREFILL
 
 
@@ -332,6 +335,34 @@ class TestChunkedPrefillEquivalence:
         prop()
 
 
+def test_build_executor_refuses_encdec():
+    """An encdec model is refused, never served as PRNG bytes."""
+    with pytest.raises(ValueError, match="encdec"):
+        build_executor("seamless_m4t_medium", smoke=True)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """The environment's cache dir wins untouched; else one fixed path in
+    the checkout, the same on every call."""
+    from repro.launch import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    default = Path(__file__).resolve().parents[1] / ".jax_cache"
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.enable_compile_cache()
+        assert got == compile_cache.enable_compile_cache()
+        assert got == (str(tmp_path) if from_env else str(default))
+        assert jax.config.jax_compilation_cache_dir == (
+            was if from_env else str(default))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
 # --------------------------------------------------------------------------
 # engine end-to-end (synthetic executor: real data path + pins, no model)
 # --------------------------------------------------------------------------
@@ -361,6 +392,25 @@ class TestEngineEndToEnd:
         assert set(kinds_by_req) == set(range(5))
         for kinds in kinds_by_req.values():
             assert kinds == {"admit", "prefill_chunk", "decode", "evict"}
+        # the run's own gates pass, and every emitted token is kept
+        assert gate_failures(report, 5) == []
+        assert sorted(r.req_id for r in eng.finished) == list(range(5))
+        assert all(len(r.tokens) == r.decoded == r.gen for r in eng.finished)
+
+    @pytest.mark.parametrize("key,value,needle", [
+        ("tiered_equiv_ok", False, "mismatch"),
+        ("requests_finished", 4, "4/5 requests"),
+        ("alloc_in_use_end", 3, "page leak"),
+        ("pages_recycled", 6, "conservation"),
+        ("trace_totals_ok", False, "trace event totals"),
+    ])
+    def test_each_gate_names_its_failure(self, key, value, needle):
+        report = {"tiered_equiv_ok": True, "requests_finished": 5,
+                  "alloc_in_use_end": 0, "pages_allocated": 7,
+                  "pages_recycled": 7}
+        assert gate_failures(report, 5) == []
+        fails = gate_failures({**report, key: value}, 5)
+        assert len(fails) == 1 and needle in fails[0], fails
 
     def test_gang_ttft_never_beats_continuous(self):
         _, cont = _run_engine()

@@ -239,7 +239,8 @@ class TestShardMapDataPlane:
         scheds = jnp.asarray(np.stack([np.arange(T) % N,
                                        (np.arange(T) * 3 + 7) % N]),
                              jnp.int32)
-        mesh = jax.make_mesh((4,), ("fabric",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("fabric",))
         for placement in ("block", "interleave"):
             fab = ShardedPoolCfg(n_shards=4, placement=placement,
                                  link_budget=2)
@@ -259,8 +260,8 @@ class TestShardMapDataPlane:
         # tiered sweep: sharded cold KV, logits bit-identical to flat pool
         B, NPPS, PS, HKV, HQ, DH = 2, 8, 4, 2, 4, 8
         NP = B * NPPS
-        k = jax.random.normal(jax.random.PRNGKey(0), (NP, PS, HKV, DH))
-        v = jax.random.normal(jax.random.PRNGKey(1), (NP, PS, HKV, DH))
+        k = jax.random.normal(jax.random.PRNGKey(0), (NP, HKV, PS, DH))
+        v = jax.random.normal(jax.random.PRNGKey(1), (NP, HKV, PS, DH))
         cold = {"k": k, "v": v}
         q = jax.random.normal(jax.random.PRNGKey(2), (B, 1, HQ, DH))
         lengths = jnp.asarray([29, 17], jnp.int32)
@@ -279,14 +280,30 @@ class TestShardMapDataPlane:
                                       jnp.int32(0), pt, lengths)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(flat))
 
+        # sharded engine: the sweep leaves the hot tier replicated over
+        # the mesh, and attention (a Mosaic kernel on a TPU, which XLA
+        # cannot partition) reads it from one device
+        from repro.serving import ServeConfig, ServingEngine, SyntheticExecutor
+        from repro.serving.engine import gate_failures
+        eng = ServingEngine(ServeConfig(requests=3, slots=2, prompt_len=8,
+                                        gen=3, page_size=4, shards=4,
+                                        attn_kernel="fused_async",
+                                        async_datapath=True),
+                            SyntheticExecutor(n_kv_heads=2, head_dim=8))
+        assert gate_failures(eng.run(), 3) == []
+        devs = lambda t: {len(x.sharding.device_set)
+                          for x in jax.tree.leaves(t)}
+        assert devs(eng.tstate) == {4}, devs(eng.tstate)
+        assert devs(eng.attention_state()) == {1}
+
         # serving 'pages' rule: preference order — one axis, never a
         # fabric x data product (that would split a shard's home slice)
         from jax.sharding import PartitionSpec
         from repro.distributed.sharding import RULES_SERVE, named_sharding_for
-        m2 = jax.make_mesh((2, 2), ("fabric", "data"))
+        m2 = make_mesh((2, 2), ("fabric", "data"))
         sh = named_sharding_for(("pages", None), (64, 4), m2, RULES_SERVE)
         assert sh.spec == PartitionSpec("fabric", None), sh.spec
-        m3 = jax.make_mesh((2, 2), ("data", "model"))
+        m3 = make_mesh((2, 2), ("data", "model"))
         sh = named_sharding_for(("pages", None), (64, 4), m3, RULES_SERVE)
         assert sh.spec == PartitionSpec("data", None), sh.spec
         print("SHARDED-OK")
@@ -318,8 +335,8 @@ class TestTieredFabricComposition:
                                             tiered_sweep)
         B, NPPS, PS, HKV, HQ, DH = 4, 8, 4, 2, 4, 8
         NP = B * NPPS
-        k = jax.random.normal(jax.random.PRNGKey(0), (NP, PS, HKV, DH))
-        v = jax.random.normal(jax.random.PRNGKey(1), (NP, PS, HKV, DH))
+        k = jax.random.normal(jax.random.PRNGKey(0), (NP, HKV, PS, DH))
+        v = jax.random.normal(jax.random.PRNGKey(1), (NP, HKV, PS, DH))
         cold = {"k": k, "v": v}
         q = jax.random.normal(jax.random.PRNGKey(2), (B, 1, HQ, DH))
         lengths = jnp.asarray([29, 17, 32, 5], jnp.int32)

@@ -29,10 +29,10 @@ N_PAGES = B * NPPS
 
 
 def _cold(seed=0):
-    k = jax.random.normal(jax.random.PRNGKey(seed), (N_PAGES, PS, HKV, DH),
+    k = jax.random.normal(jax.random.PRNGKey(seed), (N_PAGES, HKV, PS, DH),
                           jnp.float32)
     v = jax.random.normal(jax.random.PRNGKey(seed + 1),
-                          (N_PAGES, PS, HKV, DH), jnp.float32)
+                          (N_PAGES, HKV, PS, DH), jnp.float32)
     return {"k": k, "v": v}
 
 
@@ -189,7 +189,7 @@ class TestWriteCoherence:
         st = tiered_init(geom, B, jnp.float32)
         st, _ = tiered_sweep(st, cold, pt, geom, async_datapath=True)
         # mutate page 3 of request 0's context (in range of length 29)
-        new_page = jax.random.normal(jax.random.PRNGKey(9), (PS, HKV, DH))
+        new_page = jax.random.normal(jax.random.PRNGKey(9), (HKV, PS, DH))
         cold2 = {"k": cold["k"].at[3].set(new_page), "v": cold["v"]}
         # stale hot copy without invalidation -> shows the bug the API fixes
         st_stale, _ = tiered_sweep(st, cold2, pt, geom, async_datapath=True)

@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis")
-from hypothesis import given, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.history import AccessHistory
 from repro.core.window import (PrefetchWindow, _round_up_pow2_jax,
@@ -31,6 +31,9 @@ def test_history_requires_pow2():
         AccessHistory(12)
 
 
+# No per-example deadline: the jitted twin compiles on the first example,
+# which can exceed hypothesis's 200 ms default on a loaded CPU.
+@settings(deadline=None)
 @given(st.integers(1, 1 << 20))
 def test_round_up_pow2(x):
     p = round_up_pow2(x)
@@ -97,6 +100,11 @@ class TestTwinEquivalence:
         assert int(state["c_hit"]) == ref.c_hit == 0
         return state, pw_r
 
+    # No per-example deadline: up to 60 jitted window steps per example,
+    # and the first example of each pw_max pays its compiles (hundreds of
+    # ms on a loaded CPU), which hypothesis reports as flaky timing rather
+    # than a property failure.
+    @settings(deadline=None)
     @given(st.lists(st.tuples(st.integers(0, 20), st.booleans()),
                     min_size=1, max_size=60),
            st.sampled_from([4, 8, 16, 64]))
@@ -106,6 +114,7 @@ class TestTwinEquivalence:
         for hits, follows in events:
             state, _ = self._step_both(ref, state, hits, follows, pw_max)
 
+    @settings(deadline=None)      # jitted twin: same reason as above
     @given(st.integers(7, 40), st.integers(1, 2), st.booleans())
     def test_twins_agree_through_the_shrink_branch(self, big, small,
                                                    follows):
