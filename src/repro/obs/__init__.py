@@ -5,7 +5,7 @@
 * :mod:`repro.obs.export`  — Chrome trace-event (Perfetto) JSON + JSONL.
 * :mod:`repro.obs.diff`    — first-divergent-event trace differ.
 * :mod:`repro.obs.metrics` — counter/histogram registry, the unified
-  percentile ladder, device-sync'd span timers.
+  percentile ladder, span timers on the profiler's clock.
 """
 
 from .diff import (Divergence, assert_traces_equal, diff_report,
@@ -15,14 +15,14 @@ from .export import (read_jsonl, read_request_jsonl, to_chrome_trace,
 from .metrics import Registry, percentile_ladder
 from .trace import (AGGREGATE_KINDS, DEMAND_KINDS, KINDS, REQUEST_PHASES,
                     SUMMARY_KINDS, Event, RequestPhase, TraceRecorder,
-                    debug_tap, decode_stream_events, decode_sweep_events,
+                    decode_stream_events, decode_sweep_events,
                     events_to_counts, home_of_host, summary_events)
 
 __all__ = [
     "AGGREGATE_KINDS", "DEMAND_KINDS", "Divergence", "Event", "KINDS",
     "REQUEST_PHASES", "Registry", "RequestPhase", "SUMMARY_KINDS",
     "TraceRecorder", "assert_traces_equal",
-    "debug_tap", "decode_stream_events", "decode_sweep_events",
+    "decode_stream_events", "decode_sweep_events",
     "diff_report", "events_to_counts", "first_divergence", "home_of_host",
     "percentile_ladder", "read_jsonl", "read_request_jsonl",
     "summary_events", "to_chrome_trace",

@@ -13,11 +13,14 @@ all of them now delegate to (DESIGN.md §8.4):
   latency/size histograms; one registry per run, summarized once at the
   end. ``launch/serve.py`` builds its per-request TTFT + token-latency
   report on it.
-* :meth:`Registry.span` — wall-clock span timer around device work. JAX
-  dispatch is async, so a naive ``perf_counter`` pair times the *enqueue*;
-  the span handle's ``sync`` hook blocks on the result inside the timed
-  window (``jax.block_until_ready``) so the recorded duration covers the
-  device work the caller actually waited for.
+* :meth:`Registry.span` — a host span: a ``jax.profiler.TraceAnnotation``
+  for its duration, so it lands on the profiler's clock beside the device
+  ops whenever a trace is being recorded, and a histogram sample of its
+  wall time. JAX dispatch is async, so by default the span times the
+  *enqueue*; a body that sets the handle's ``sync`` makes the span block
+  on that result (``jax.block_until_ready``) before the clock stops, so
+  the duration covers the device work. :meth:`Registry.phase` is the
+  annotation alone, for a block no histogram reader needs.
 
 Everything here is host-side Python — nothing in this module is jitted or
 traced, and nothing touches the hot data path.
@@ -25,7 +28,6 @@ traced, and nothing touches the hot data path.
 
 from __future__ import annotations
 
-import contextlib
 import math
 import time
 
@@ -90,24 +92,48 @@ class Histogram:
         return percentile_ladder(self.samples, qs)
 
 
-class _SpanHandle:
-    """Mutable box a :meth:`Registry.span` body parks its device result in.
+class _Span:
+    """One open :meth:`Registry.span`: the profiler annotation, the start
+    time and the ``sync`` box its body may park a device result in.
 
     Setting ``sync`` to a jax array/pytree makes the span block on it
     before stopping the clock, so the measured wall time includes the
     device work rather than just its dispatch.
     """
 
-    __slots__ = ("sync",)
+    __slots__ = ("sync", "_hist", "_ann", "_t0")
 
-    def __init__(self):
+    def __init__(self, hist: Histogram, ann):
         self.sync = None
+        self._hist = hist
+        self._ann = ann
+
+    def __enter__(self) -> "_Span":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        try:
+            if self.sync is not None:
+                import jax
+                jax.block_until_ready(self.sync)
+            self._hist.observe(time.perf_counter() - self._t0)
+        finally:
+            self._ann.__exit__(*exc)
 
 
 class Registry:
-    """Named counters + histograms for one run; summarized at the end."""
+    """Named counters + histograms for one run; summarized at the end.
 
-    def __init__(self):
+    ``prefix`` is put before every span's name in the profiler trace (the
+    histogram keeps the bare name): the serving engine's registry uses
+    ``"engine."``, so its ``tiered_sweep`` span is the histogram
+    ``tiered_sweep`` and the trace annotation ``engine.tiered_sweep``.
+    """
+
+    def __init__(self, prefix: str = ""):
+        self.prefix = prefix
         self._counters: dict[str, Counter] = {}
         self._hists: dict[str, Histogram] = {}
 
@@ -121,24 +147,29 @@ class Registry:
             self._hists[name] = Histogram(name)
         return self._hists[name]
 
-    @contextlib.contextmanager
-    def span(self, name: str):
-        """Time a block into histogram ``name`` (seconds), device-sync'd.
+    def span(self, name: str, **meta) -> _Span:
+        """Time a block into histogram ``name`` (seconds) and annotate it
+        in the profiler trace as ``prefix + name``, with ``meta`` as the
+        annotation's metadata (e.g. ``req_id=``). When no trace is being
+        recorded the annotation costs about a microsecond.
 
-        >>> with reg.span("attention") as sp:
+        >>> with reg.span("attention", req_id=3) as sp:
         ...     out = attention(...)
         ...     sp.sync = out          # block on the device result
         """
-        import jax
+        from jax.profiler import TraceAnnotation
 
-        handle = _SpanHandle()
-        t0 = time.perf_counter()
-        try:
-            yield handle
-        finally:
-            if handle.sync is not None:
-                jax.block_until_ready(handle.sync)
-            self.histogram(name).observe(time.perf_counter() - t0)
+        return _Span(self.histogram(name),
+                     TraceAnnotation(self.prefix + name, **meta))
+
+    def phase(self, name: str, **meta):
+        """Annotate a block in the profiler trace as ``prefix + name``, with
+        ``meta`` as its metadata, and keep no histogram: for a block whose
+        time only the trace reads. Costs what :meth:`span`'s annotation
+        does."""
+        from jax.profiler import TraceAnnotation
+
+        return TraceAnnotation(self.prefix + name, **meta)
 
     def summary(self, qs=DEFAULT_QS) -> dict:
         """``{"counters": {name: int}, "histograms": {name: ladder}}``."""

@@ -17,8 +17,6 @@ structured event stream (DESIGN.md §8) without touching the hot path:
 * :class:`TraceRecorder` — the push-style producer the lock-step twins
   (``fabric.linkstep`` / ``fabric.shardstep``) thread their page-level
   transitions through.
-* :func:`debug_tap` — optional ``jax.debug.callback`` bridge for emitting
-  events from *inside* a jitted function while debugging interactively.
 
 Decode contract (verified property-by-property in ``tests/test_obs.py``
 against ``pool_stats``; see also the docstrings of ``core.pool``):
@@ -330,29 +328,6 @@ class TraceRecorder:
 
     def __len__(self) -> int:
         return len(self.events)
-
-
-def debug_tap(recorder: TraceRecorder, kind: str):
-    """A jit-safe tap: call the result with traced scalars inside a jitted
-    function and the event lands in ``recorder`` host-side via
-    ``jax.debug.callback`` (ordered=True keeps program order).
-
-    Interactive-debugging aid only — the production decoders are post-hoc
-    and keep the hot path untouched.
-
-    >>> tap = debug_tap(rec, "land")
-    >>> tap(step, stream, page)        # inside a jitted fn
-    """
-    import jax
-
-    def _cb(step, stream, page, count):
-        recorder.emit(kind, int(step), int(stream), int(page),
-                      count=int(count))
-
-    def tap(step, stream, page, count=1):
-        jax.debug.callback(_cb, step, stream, page, count, ordered=True)
-
-    return tap
 
 
 def events_to_counts(events, n_streams: int) -> list[dict]:

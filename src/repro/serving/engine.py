@@ -24,10 +24,18 @@ The step executor that turns every prior subsystem into a servable engine
    (:func:`tiered_reset_stream`), and their counters fold into the
    per-slot base so the §8 event-totals pin spans slot reuse.
 
-Per-request TTFT and token-latency ladders ride
-:class:`repro.obs.metrics.Registry`; the request lifecycle is exported as
-its own Perfetto track keyed by request id (slot-reuse-proof), next to the
-per-stream page-lifecycle tracks.
+Every phase of a step is annotated on the engine's
+:class:`repro.obs.metrics.Registry` (prefix ``engine.``): while a profiler
+trace is recording, it lands on the trace's clock beside the device ops.
+``engine.step`` holds ``admit``; per slot ``prefill`` or ``decode``
+(around the executor call) and ``kv_write`` (the cold-pool scatter), each
+with ``req_id``; ``lifecycle`` (the §12 mirror); ``invalidate``;
+``sweep_inputs``, ``tiered_sweep``, ``attention`` and ``pin`` (the flat
+reference and the host compare); and per finisher ``evict``. Only two are
+also histograms, the ones a report reads: ``tiered_sweep`` (the one span
+that syncs: its time includes the device's) and ``decode``. The request lifecycle is also exported as its own Perfetto
+track keyed by request id (slot-reuse-proof), next to the per-stream
+page-lifecycle tracks.
 """
 
 from __future__ import annotations
@@ -184,7 +192,7 @@ class ServingEngine:
                 from repro.launch.mesh import make_fabric_mesh
                 mesh = make_fabric_mesh(c.shards)
             self.mesh = mesh
-        self.reg = Registry()
+        self.reg = Registry(prefix="engine.")
         self.phases: list[RequestPhase] = []
         self.events: list[Event] | None = [] if c.trace else None
         self.link_hist: list[np.ndarray] = []
@@ -209,13 +217,14 @@ class ServingEngine:
     def _write_tokens(self, req: Request, k, v, start: int) -> list[int]:
         """Mirror ``[n, Hkv, dh]`` K/V into the cold pool at positions
         ``start..start+n-1``; returns the distinct pages written."""
-        n = k.shape[0]
-        pages = [self.sched.page_for_position(req, start + j)
-                 for j in range(n)]
-        ps = self.cfg.page_size
-        pg = jnp.asarray(pages, jnp.int32)
-        off = (start + jnp.arange(n, dtype=jnp.int32)) % ps
-        self.pool = _scatter_tokens(self.pool, pg, off, k, v)
+        with self.reg.phase("kv_write", req_id=req.req_id):
+            n = k.shape[0]
+            pages = [self.sched.page_for_position(req, start + j)
+                     for j in range(n)]
+            ps = self.cfg.page_size
+            pg = jnp.asarray(pages, jnp.int32)
+            off = (start + jnp.arange(n, dtype=jnp.int32)) % ps
+            self.pool = _scatter_tokens(self.pool, pg, off, k, v)
         return sorted(set(pages))
 
     def attention_state(self) -> dict:
@@ -227,24 +236,17 @@ class ServingEngine:
             return self.tstate
         return jax.device_put(self.tstate, self.pool["k"].sharding)
 
-    def _sweep_and_pin(self, t: int, decoding: list[Request]) -> None:
-        S, npps = self.cfg.slots, self.npps
-        rows = np.full((S, npps), -1, np.int32)
-        lengths = np.zeros((S,), np.int32)
-        for req in decoding:
-            rows[req.slot, :len(req.pages)] = req.pages
-            lengths[req.slot] = req.prefilled + req.decoded - 1
-        rows_j = jnp.asarray(rows)
-        lengths_j = jnp.asarray(lengths)
-        sweep_kw = {}
-        lc = self.lifecycle
-        if lc is not None:
-            # drive the §12 lifecycle mirror between steps: decay + heat,
-            # trend-driven hot-ward migration, capacity demotion. All of it
-            # is scheduling metadata except demotion, which round-trips the
-            # victim's cold bytes once (both the flat reference and the
-            # tiered path then read the same post-roundtrip bytes, so the
-            # §6.4 pin holds) and drops any stale hot copy.
+    def _lifecycle_step(self, rows: np.ndarray,
+                        decoding: list[Request]) -> dict:
+        """Drive the §12 lifecycle mirror between steps: decay + heat,
+        trend-driven hot-ward migration, capacity demotion; returns the
+        sweep's lifecycle arguments. All of it is scheduling metadata
+        except demotion, which round-trips the victim's cold bytes once
+        (both the flat reference and the tiered path then read the same
+        post-roundtrip bytes, so the §6.4 pin holds) and drops any stale
+        hot copy."""
+        lc, S = self.lifecycle, self.cfg.slots
+        with self.reg.phase("lifecycle"):
             lc.begin_step()
             lc.touch(rows[rows >= 0])
             trend = np.asarray(self.tstate["leap"]["trend"])
@@ -271,13 +273,28 @@ class ServingEngine:
                 if self.events is not None:
                     self.events.append(Event("demote", self._chunk_clock,
                                              0, count=len(victims)))
-            sweep_kw["home_map"] = lc.home_map()
+            sweep_kw = {"home_map": lc.home_map()}
             if lc.cfg.compressed:
                 sweep_kw["comp_map"] = lc.comp_map()
                 sweep_kw["decompress_delay"] = lc.cfg.decompress_delay
-        cold = {"k": self.pool["k"][0], "v": self.pool["v"][0]}
-        q = jax.random.normal(jax.random.PRNGKey(1000 + t),
-                              (S, 1, self.hq, self.ex.head_dim), self.dtype)
+        return sweep_kw
+
+    def _sweep_and_pin(self, t: int, decoding: list[Request]) -> None:
+        S, npps = self.cfg.slots, self.npps
+        with self.reg.phase("sweep_inputs"):
+            rows = np.full((S, npps), -1, np.int32)
+            lengths = np.zeros((S,), np.int32)
+            for req in decoding:
+                rows[req.slot, :len(req.pages)] = req.pages
+                lengths[req.slot] = req.prefilled + req.decoded - 1
+            sweep_kw = ({} if self.lifecycle is None
+                        else self._lifecycle_step(rows, decoding))
+            rows_j = jnp.asarray(rows)
+            lengths_j = jnp.asarray(lengths)
+            cold = {"k": self.pool["k"][0], "v": self.pool["v"][0]}
+            q = jax.random.normal(jax.random.PRNGKey(1000 + t),
+                                  (S, 1, self.hq, self.ex.head_dim),
+                                  self.dtype)
         with self.reg.span("tiered_sweep") as sp:
             self.tstate, info = tiered_sweep(
                 self.tstate, cold, rows_j, self.geom,
@@ -286,17 +303,17 @@ class ServingEngine:
                 fabric=self.fabric, mesh=self.mesh, **sweep_kw)
             sp.sync = info
         mode = normalize_attn_kernel(self.cfg.attn_kernel)
-        with self.reg.span("tiered_attention") as sp:
+        with self.reg.phase("attention"):
             tiered, resident = tiered_attention(q, self.attention_state(),
                                                 rows_j, lengths_j,
                                                 attn_kernel=mode)
-            sp.sync = tiered
-        flat = paged_decode_attention(q, self.pool, jnp.int32(0), rows_j,
-                                      lengths_j,
-                                      use_kernel=(mode != "ref"))
-        act = [r.slot for r in decoding]
-        step_ok = bool(resident) and bool(
-            (np.asarray(tiered)[act] == np.asarray(flat)[act]).all())
+        with self.reg.phase("pin"):
+            flat = paged_decode_attention(q, self.pool, jnp.int32(0), rows_j,
+                                          lengths_j,
+                                          use_kernel=(mode != "ref"))
+            act = [r.slot for r in decoding]
+            step_ok = bool(resident) and bool(
+                (np.asarray(tiered)[act] == np.asarray(flat)[act]).all())
         if not step_ok:
             self.equiv_ok = False
             if self.first_bad_step is None:
@@ -310,82 +327,89 @@ class ServingEngine:
 
     # -- one engine step -----------------------------------------------------
     def _step(self, t: int) -> None:
-        for req in self.sched.admit_ready(self.queue, t):
-            self.ex.begin(req)
-            self.phases.append(RequestPhase("admit", req.req_id,
-                                            req.arrival_step, t, req.slot))
-        written: list[tuple[int, int]] = []       # (slot, page)
-        decoding: list[Request] = []
-        finishers: list[Request] = []
-        for req in sorted(self.sched.active(), key=lambda r: r.slot):
-            if req.state == PREFILL:
-                n = min(self.cfg.prefill_chunk,
-                        req.prompt_len - req.prefilled)
-                k, v, tok = self.ex.prefill_chunk(req, n)
-                if tok is not None:
+        with self.reg.phase("step"):
+            with self.reg.phase("admit"):
+                for req in self.sched.admit_ready(self.queue, t):
+                    self.ex.begin(req)
+                    self.phases.append(RequestPhase(
+                        "admit", req.req_id, req.arrival_step, t, req.slot))
+            written: list[tuple[int, int]] = []       # (slot, page)
+            decoding: list[Request] = []
+            finishers: list[Request] = []
+            for req in sorted(self.sched.active(), key=lambda r: r.slot):
+                if req.state == PREFILL:
+                    n = min(self.cfg.prefill_chunk,
+                            req.prompt_len - req.prefilled)
+                    with self.reg.phase("prefill", req_id=req.req_id):
+                        k, v, tok = self.ex.prefill_chunk(req, n)
+                    if tok is not None:
+                        req.tokens.append(tok)
+                    pages = self._write_tokens(req, k, v, req.prefilled)
+                    written.extend((req.slot, p) for p in pages)
+                    req.advance_prefill(n, t)
+                    self.phases.append(RequestPhase(
+                        "prefill_chunk", req.req_id, t, t + 1, req.slot, n))
+                    if req.state == DECODE:       # prompt done: TTFT token
+                        self.reg.histogram("ttft_steps").observe(
+                            req.ttft_steps)
+                        if req.decoded >= req.gen:
+                            finishers.append(req)
+                elif req.state == DECODE:
+                    pos = req.prefilled + req.decoded - 1
+                    with self.reg.span("decode", req_id=req.req_id):
+                        k, v, tok = self.ex.decode(req)
                     req.tokens.append(tok)
-                pages = self._write_tokens(req, k, v, req.prefilled)
-                written.extend((req.slot, p) for p in pages)
-                req.advance_prefill(n, t)
-                self.phases.append(RequestPhase("prefill_chunk", req.req_id,
-                                                t, t + 1, req.slot, n))
-                if req.state == DECODE:           # prompt done: TTFT token
-                    self.reg.histogram("ttft_steps").observe(req.ttft_steps)
-                    if req.decoded >= req.gen:
+                    pages = self._write_tokens(req, k[None], v[None], pos)
+                    written.extend((req.slot, p) for p in pages)
+                    done = req.advance_decode(t)
+                    decoding.append(req)
+                    if done:
                         finishers.append(req)
-            elif req.state == DECODE:
-                pos = req.prefilled + req.decoded - 1
-                with self.reg.span("token_latency") as sp:
-                    k, v, tok = self.ex.decode(req)
-                    sp.sync = k
-                req.tokens.append(tok)
-                pages = self._write_tokens(req, k[None], v[None], pos)
-                written.extend((req.slot, p) for p in pages)
-                done = req.advance_decode(t)
-                decoding.append(req)
-                if done:
-                    finishers.append(req)
-        if written and self.lifecycle is not None:
-            # freshly written bytes are uncompressed by construction: clear
-            # the comp bit (else a recycled page would charge a decompress
-            # surcharge — and dodge its roundtrip — on stale state)
-            n_prom = self.lifecycle.promote([p for _, p in written])
-            if n_prom and self.events is not None:
-                self.events.append(Event("promote", self._chunk_clock, 0,
-                                         count=n_prom))
-        if written:
-            inv = np.full((self._inv_width,), -1, np.int32)
-            inv[:len(written)] = [p for _, p in written]
-            inv_j = jnp.broadcast_to(jnp.asarray(inv)[None],
-                                     (self.cfg.slots, self._inv_width))
-            self.tstate = tiered_invalidate(self.tstate, inv_j)
-            if self.events is not None:
-                self.events.extend(
-                    Event("invalidate", self._chunk_clock, s, page=p,
-                          seq=self.allocator.stamp_of(p))
-                    for s, p in written)
-        if decoding:
-            self._sweep_and_pin(t, decoding)
-        self.occupancy_peak = max(self.occupancy_peak,
-                                  self.allocator.occupancy())
-        for req in finishers:
-            self._evict(req, t)
+            if written and self.lifecycle is not None:
+                # freshly written bytes are uncompressed by construction:
+                # clear the comp bit (else a recycled page would charge a
+                # decompress surcharge — and dodge its roundtrip — on
+                # stale state)
+                with self.reg.phase("lifecycle"):
+                    n_prom = self.lifecycle.promote([p for _, p in written])
+                if n_prom and self.events is not None:
+                    self.events.append(Event("promote", self._chunk_clock, 0,
+                                             count=n_prom))
+            if written:
+                with self.reg.phase("invalidate"):
+                    inv = np.full((self._inv_width,), -1, np.int32)
+                    inv[:len(written)] = [p for _, p in written]
+                    inv_j = jnp.broadcast_to(jnp.asarray(inv)[None],
+                                             (self.cfg.slots, self._inv_width))
+                    self.tstate = tiered_invalidate(self.tstate, inv_j)
+                if self.events is not None:
+                    self.events.extend(
+                        Event("invalidate", self._chunk_clock, s, page=p,
+                              seq=self.allocator.stamp_of(p))
+                        for s, p in written)
+            if decoding:
+                self._sweep_and_pin(t, decoding)
+            self.occupancy_peak = max(self.occupancy_peak,
+                                      self.allocator.occupancy())
+            for req in finishers:
+                self._evict(req, t)
 
     def _evict(self, req: Request, t: int) -> None:
-        self.phases.append(RequestPhase("decode", req.req_id,
-                                        req.first_token_step, t, req.slot,
-                                        req.decoded))
-        slot = req.slot
-        stats = tiered_stats(self.tstate, slot)
-        base = self.counter_base[slot]
-        for key in PINNED_COUNTERS:
-            base[key] += int(stats[key])
-        self.tstate = tiered_reset_stream(self.tstate, slot, self.geom,
-                                          self.dtype)
-        self.sched.finish(req, t)
-        self.ex.end(req)
-        self.finished.append(req)
-        self.phases.append(RequestPhase("evict", req.req_id, t, t, slot))
+        with self.reg.phase("evict", req_id=req.req_id):
+            self.phases.append(RequestPhase("decode", req.req_id,
+                                            req.first_token_step, t,
+                                            req.slot, req.decoded))
+            slot = req.slot
+            stats = tiered_stats(self.tstate, slot)
+            base = self.counter_base[slot]
+            for key in PINNED_COUNTERS:
+                base[key] += int(stats[key])
+            self.tstate = tiered_reset_stream(self.tstate, slot, self.geom,
+                                              self.dtype)
+            self.sched.finish(req, t)
+            self.ex.end(req)
+            self.finished.append(req)
+            self.phases.append(RequestPhase("evict", req.req_id, t, t, slot))
 
     # -- run -----------------------------------------------------------------
     def run(self) -> dict:
@@ -401,13 +425,17 @@ class ServingEngine:
                 raise RuntimeError(
                     f"engine livelock: {len(self.queue)} queued / "
                     f"{len(self.sched.active())} active after {t} steps")
-            with self.reg.span("engine_step"):
-                self._step(t)
+            self._step(t)
             t += 1
         wall = time.perf_counter() - t0
         return self._report(t, wall)
 
     def _report(self, steps: int, wall: float) -> dict:
+        """The run's summary. ``token_latency`` is the ladder of the
+        ``decode`` span: the host time of one slot's ``ex.decode`` call.
+        The span does not sync, so it holds the device's decode only where
+        the executor waits on it (``ModelExecutor``'s ``int(argmax)``);
+        with ``SyntheticExecutor`` it is the K/V draw's dispatch."""
         c = self.cfg
         totals = []
         for s in range(c.slots):
@@ -437,7 +465,7 @@ class ServingEngine:
             "ttft_steps": rnd(ttfts.ladder()),
             "mean_ttft_steps": round(float(np.mean(ttfts.samples)), 3)
             if ttfts.samples else float("nan"),
-            "token_latency": rnd(self.reg.histogram("token_latency").ladder()),
+            "token_latency": rnd(self.reg.histogram("decode").ladder()),
             "pages_allocated": self.sched.pages_allocated,
             "pages_recycled": self.sched.pages_recycled,
             "alloc_in_use_end": self.allocator.in_use,

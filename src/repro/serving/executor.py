@@ -103,6 +103,7 @@ class ModelExecutor:
         self._prompts: dict[int, jax.Array] = {}
         self._last_tok: dict[int, int] = {}
         self.last_logits: dict[int, jax.Array] = {}
+        self.n_q_heads = cfg.n_heads
         self.n_kv_heads = cfg.n_kv_heads
         self.head_dim = cfg.head_dim
         self.dtype = jnp.dtype(cfg.dtype).name

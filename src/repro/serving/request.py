@@ -17,11 +17,17 @@ State semantics:
   prompt token — that step stamps TTFT.
 * ``DECODE``  — one output token per engine step until ``gen`` tokens.
 * ``FINISHED``— evicted: pages recycled, slot freed, stream state reset.
+
+Beside the arrival and admit step stamps sit host-clock stamps
+(``time.perf_counter_ns()``, ``-1`` until stamped): enqueued
+(:meth:`AdmissionQueue.push`) and admitted. The benchmark's
+``queue_wait_ms`` reads admitted minus enqueued.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import time
 
 WAITING = "WAITING"
 PREFILL = "PREFILL"
@@ -64,6 +70,8 @@ class Request:
     admit_step: int = -1
     first_token_step: int = -1
     finish_step: int = -1
+    t_enqueued_ns: int = dataclasses.field(default=-1, compare=False)
+    t_admitted_ns: int = dataclasses.field(default=-1, compare=False)
 
     def __post_init__(self):
         if self.prompt_len < 1 or self.gen < 1:
@@ -100,6 +108,7 @@ class Request:
         self.state = state
         if state == PREFILL:
             self.admit_step = step
+            self.t_admitted_ns = time.perf_counter_ns()
         elif state == FINISHED:
             self.finish_step = step
 

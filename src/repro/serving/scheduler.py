@@ -26,6 +26,8 @@ Discipline (DESIGN.md §10):
 
 from __future__ import annotations
 
+import time
+
 from repro.paging.kv_cache import PageAllocator
 
 from .request import DECODE, FINISHED, PREFILL, WAITING, Request
@@ -37,15 +39,18 @@ class AdmissionQueue:
     def __init__(self, requests=()):
         self._pending: list[Request] = sorted(
             requests, key=lambda r: (r.arrival_step, r.req_id))
+        now = time.perf_counter_ns()
         for r in self._pending:
             if r.state != WAITING:
                 raise ValueError(f"request {r.req_id} enqueued in state "
                                  f"{r.state}")
+            r.t_enqueued_ns = now
 
     def __len__(self) -> int:
         return len(self._pending)
 
     def push(self, req: Request) -> None:
+        req.t_enqueued_ns = time.perf_counter_ns()
         self._pending.append(req)
         self._pending.sort(key=lambda r: (r.arrival_step, r.req_id))
 

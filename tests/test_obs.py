@@ -116,6 +116,61 @@ class TestRegistry:
             sp.sync = jnp.arange(8).sum()        # forces block_until_ready
         assert reg.histogram("work").samples[0] > 0.0
 
+    def test_span_without_sync_never_blocks(self, monkeypatch):
+        import jax
+        calls = []
+        monkeypatch.setattr(jax, "block_until_ready", calls.append)
+        reg = Registry()
+        with reg.span("work"):
+            jnp.arange(8).sum()
+        assert calls == [] and len(reg.histogram("work").samples) == 1
+
+    @pytest.mark.filterwarnings("ignore:builtin type event_stats")
+    def test_span_is_a_profiler_annotation_with_metadata(self, tmp_path):
+        """The span lands in the profiler's trace as ``prefix + name`` with
+        its metadata, beside a histogram under the bare name."""
+        import jax
+        from jax.profiler import ProfileData
+        reg = Registry(prefix="engine.")
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with reg.span("outer"):
+                with reg.span("inner", req_id=7) as sp:
+                    sp.sync = jnp.arange(8).sum()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.rglob("*.xplane.pb")
+        evs = {ev.name: ev for p in ProfileData.from_file(str(path)).planes
+               for ln in p.lines for ev in ln.events
+               if ev.name.startswith("engine.")}
+        assert set(evs) == {"engine.outer", "engine.inner"}
+        inner, outer = evs["engine.inner"], evs["engine.outer"]
+        assert dict(inner.stats) == {"req_id": 7}
+        assert outer.start_ns <= inner.start_ns
+        assert inner.start_ns + inner.duration_ns \
+            <= outer.start_ns + outer.duration_ns
+        assert len(reg.histogram("inner").samples) == 1
+        assert len(reg.histogram("outer").samples) == 1
+
+    @pytest.mark.filterwarnings("ignore:builtin type event_stats")
+    def test_phase_is_an_annotation_without_a_histogram(self, tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+        reg = Registry(prefix="engine.")
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with reg.phase("write", req_id=3):
+                jnp.arange(8).sum()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.rglob("*.xplane.pb")
+        evs = [ev for p in ProfileData.from_file(str(path)).planes
+               for ln in p.lines for ev in ln.events
+               if ev.name.startswith("engine.")]
+        assert [(ev.name, dict(ev.stats)) for ev in evs] \
+            == [("engine.write", {"req_id": 3})]
+        assert reg.summary()["histograms"] == {}
+
     def test_empty_ladder_is_nan(self):
         lad = percentile_ladder([])
         assert lad["n"] == 0 and math.isnan(lad["p50"])

@@ -335,6 +335,16 @@ class TestChunkedPrefillEquivalence:
         prop()
 
 
+def test_model_executor_gives_the_engine_its_query_heads():
+    """The engine draws its query with the executor's own ``n_q_heads``,
+    so a GQA model's query has ``n_heads`` heads, not ``n_kv_heads``."""
+    ex = _smoke_model_executor()
+    assert ex.n_q_heads == ex.cfg.n_heads != ex.n_kv_heads
+    eng = ServingEngine(ServeConfig(requests=0, slots=1, prompt_len=4, gen=2,
+                                    page_size=4), ex)
+    assert eng.hq == ex.cfg.n_heads
+
+
 def test_build_executor_refuses_encdec():
     """An encdec model is refused, never served as PRNG bytes."""
     with pytest.raises(ValueError, match="encdec"):
@@ -431,6 +441,125 @@ class TestEngineEndToEnd:
         assert report["alloc_in_use_end"] == 0
         # requests (5) > slots (2): the run necessarily hit partial batches
         assert report["steps"] > 0
+
+
+# --------------------------------------------------------------------------
+# engine phase spans (profiler clock), syncs, request stamps, report keys
+# --------------------------------------------------------------------------
+#: the ``engine.*`` phase spans every engine step can open; ``lifecycle``
+#: only with the §12 mirror on
+PHASES = ("admit", "prefill", "decode", "kv_write", "invalidate",
+          "sweep_inputs", "tiered_sweep", "attention", "pin", "evict")
+#: the phases opened once per request and step, tagged with its ``req_id``
+PER_REQUEST = ("prefill", "decode", "kv_write", "evict")
+REPORT_KEYS = {"requests", "slots", "arrival", "admission", "steps",
+               "wall_s", "tiered_equiv_ok", "requests_finished",
+               "tokens_decoded", "ttft_steps", "mean_ttft_steps",
+               "token_latency", "pages_allocated", "pages_recycled",
+               "alloc_in_use_end", "alloc_occupancy_peak",
+               "prefetch_hits_total", "deferred_total"}
+
+
+def _engine_events(trace_dir):
+    """``(name, start_ns, end_ns, stats)`` of every host annotation named
+    ``engine*`` in the profiler trace written under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = Path(trace_dir).rglob("*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+             dict(ev.stats))
+            for plane in pd.planes if not plane.name.startswith("/device:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("engine")]
+
+
+class TestEngineSpans:
+    @pytest.mark.filterwarnings("ignore:builtin type event_stats")
+    @pytest.mark.parametrize("migration", [None, "lifecycle"])
+    def test_phase_spans_nest_under_step_on_the_profiler_clock(
+            self, tmp_path, migration):
+        from repro.paging.lifecycle import MigrationCfg
+        mig = (None if migration is None else
+               MigrationCfg(mig_per_stream=2, lead=1, cooldown=8))
+        _run_engine(migration=mig)                 # compile outside the trace
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            eng, report = _run_engine(migration=mig)
+        finally:
+            jax.profiler.stop_trace()
+        assert report["tiered_equiv_ok"]
+        events = _engine_events(tmp_path)
+        names = {n for n, *_ in events}
+        assert "engine_step" not in names          # the harness's step label
+        steps = [(a, b) for n, a, b, _ in events if n == "engine.step"]
+        assert len(steps) == report["steps"]
+        want = PHASES + (("lifecycle",) if migration else ())
+        for phase in want:
+            spans = [e for e in events if e[0] == f"engine.{phase}"]
+            assert spans, phase
+            for _, a, b, stats in spans:
+                assert any(lo <= a and b <= hi for lo, hi in steps), phase
+                if phase in PER_REQUEST:
+                    assert stats["req_id"] in range(5), (phase, stats)
+        # the spans of one request share its id
+        for phase in PER_REQUEST:
+            ids = {e[3]["req_id"] for e in events if e[0] == f"engine.{phase}"}
+            assert ids == set(range(5)), phase
+        assert not any(n.startswith("engine.") and n[7:] not in
+                       want + ("step",) for n in names)
+
+    def test_decoding_step_blocks_once_from_tiered_sweep(self, monkeypatch):
+        cfg = ServeConfig(requests=3, slots=2, prompt_len=8, gen=4,
+                          page_size=4, prefill_chunk=4, arrival="constant",
+                          think_time=1000.0, seed=3)
+        eng = ServingEngine(cfg, SyntheticExecutor(n_kv_heads=2, head_dim=8))
+        calls = []
+        real = jax.block_until_ready
+        monkeypatch.setattr(jax, "block_until_ready",
+                            lambda x: calls.append(x) or real(x))
+        sweeps = eng.reg.histogram("tiered_sweep").samples
+        t = decoding_steps = 0
+        while len(eng.queue) or eng.sched.active():
+            n_calls, n_sweeps = len(calls), len(sweeps)
+            eng._step(t)
+            swept = len(sweeps) - n_sweeps
+            assert len(calls) - n_calls == swept, t
+            decoding_steps += swept
+            t += 1
+        assert decoding_steps > 0
+        assert "tiered_attention" not in eng.reg._hists
+        assert "engine_step" not in eng.reg._hists
+
+    def test_only_spans_a_report_reads_keep_histograms(self):
+        """Every other phase is a trace annotation alone: a run's registry
+        holds the sweep (``sweep_ms``), the decode call (``token_latency``)
+        and the TTFT steps, one sample per call and no more."""
+        eng, report = _run_engine()
+        assert set(eng.reg._hists) == {"tiered_sweep", "decode", "ttft_steps"}
+        assert len(eng.reg.histogram("decode").samples) == sum(
+            r.gen - 1 for r in eng.finished)
+        assert len(eng.reg.histogram("ttft_steps").samples) == 5
+
+    def test_request_stamps_are_ordered(self):
+        eng, report = _run_engine()
+        assert len(eng.finished) == 5
+        for r in eng.finished:
+            assert 0 < r.t_enqueued_ns <= r.t_admitted_ns, r.req_id
+        # and a pushed request is stamped when it is pushed
+        q = AdmissionQueue()
+        req = Request(req_id=9, prompt_len=2, gen=1)
+        assert req.t_enqueued_ns == -1
+        q.push(req)
+        assert req.t_enqueued_ns > 0
+
+    @pytest.mark.parametrize("trace", [False, True])
+    def test_report_keys_unchanged(self, trace):
+        eng, report = _run_engine(trace=trace)
+        extra = {"trace_totals_ok", "trace_events"} if trace else set()
+        assert set(report) == REPORT_KEYS | extra
+        # one token latency per decode call
+        assert report["token_latency"]["n"] == sum(
+            r.gen - 1 for r in eng.finished)
 
 
 # --------------------------------------------------------------------------
