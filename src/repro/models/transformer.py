@@ -317,23 +317,48 @@ def _attn_decode(p, cfg: ModelConfig, y, st, pos, angles):
     q, k, v = _qkv(p, cfg, y, angles)                    # S=1
     T = st["k"].shape[1]
     slot = jnp.mod(pos, T) if cfg.sliding_window else pos
-    k_cache = jax.lax.dynamic_update_slice(st["k"], k, (0, slot, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(st["v"], v, (0, slot, 0, 0))
     length = jnp.minimum(pos + 1, T)
+    if jnp.ndim(pos):
+        # a position per row: each row writes its own slot; a row at a
+        # negative position writes nothing and attends over nothing
+        live = pos >= 0
+        at = (live[:, None] & (jnp.arange(T)[None, :] == slot[:, None]))
+        at = at[:, :, None, None]
+        k_cache = jnp.where(at, k, st["k"])
+        v_cache = jnp.where(at, v, st["v"])
+        length = jnp.where(live, length, 0)
+    else:
+        k_cache = jax.lax.dynamic_update_slice(st["k"], k, (0, slot, 0, 0))
+        v_cache = jax.lax.dynamic_update_slice(st["v"], v, (0, slot, 0, 0))
     o = decode_attention(q, k_cache, v_cache, length,
                          softcap=cfg.attn_logit_softcap)
     return o.reshape(B, 1, -1) @ p["wo"], {"k": k_cache, "v": v_cache}
 
 
 def decode_step(params, cfg: ModelConfig, token, state, embeds=None):
-    """One token for every stream: token [B] int32 -> (logits [B,V], state)."""
+    """One token for every stream: token [B] int32 -> (logits [B,V], state).
+
+    ``state["pos"]`` is one position for every row (``[]``) or one per row
+    (``[B]``): each row then takes its own RoPE angles, cache slot and
+    attention length, and a row at a negative position is idle: its cache,
+    recurrent state and position stay as they were (its logits are
+    meaningless)."""
     P = cfg.scan_period()
     kinds = cfg.layer_kinds()[:P]
-    pos = state["pos"]
+    pos = jnp.asarray(state["pos"])
+    per_row = pos.ndim == 1
     x = (embeds if embeds is not None
          else embed_tokens(params, cfg, token[:, None]))   # [B,1,D]
-    positions = pos[None]                                  # [1]
+    positions = pos[:, None] if per_row else pos[None]     # [B,1] or [1]
     angles = _angles_for(cfg, positions, None)
+
+    def keep_idle(new, old):
+        """Recurrent state of idle rows (negative ``pos``) left as it was."""
+        if not per_row:
+            return new
+        live = pos >= 0
+        return jax.tree.map(lambda n, o: jnp.where(
+            live.reshape((-1,) + (1,) * (n.ndim - 1)), n, o), new, old)
 
     def period(x, xs):
         pp, ps = xs
@@ -346,14 +371,14 @@ def decode_step(params, cfg: ModelConfig, token, state, embeds=None):
                 o, st = _attn_decode(p["mix"], cfg, y, st, pos, angles)
                 x = x + o
             elif mix == "mamba":
-                o, st = mamba_decode_step(p["mix"], y, st, cfg.mamba_d_state)
-                x = x + o
+                o, new = mamba_decode_step(p["mix"], y, st, cfg.mamba_d_state)
+                x, st = x + o, keep_idle(new, st)
             elif mix == "mlstm":
-                o, st = mlstm_decode_step(p["mix"], y, st, cfg.n_heads)
-                x = x + o
+                o, new = mlstm_decode_step(p["mix"], y, st, cfg.n_heads)
+                x, st = x + o, keep_idle(new, st)
             elif mix == "slstm":
-                o, st = slstm_decode_step(p["mix"], y, st, cfg.n_heads)
-                x = x + o
+                o, new = slstm_decode_step(p["mix"], y, st, cfg.n_heads)
+                x, st = x + o, keep_idle(new, st)
             if kind["ff"] == "mlp":
                 y2 = apply_norm(p["norm2"], x, cfg.norm, cfg.norm_eps)
                 x = x + apply_mlp(p["ff"], y2, cfg.act)
@@ -369,7 +394,8 @@ def decode_step(params, cfg: ModelConfig, token, state, embeds=None):
                                  (params["period"], state["blocks"]))
     h = apply_norm(params["final_norm"], x[:, 0], cfg.norm, cfg.norm_eps)
     logits = (h @ lm_head(params, cfg)).astype(jnp.float32)
-    return logits, {"blocks": new_blocks, "pos": pos + 1}
+    new_pos = jnp.where(pos >= 0, pos + 1, pos) if per_row else pos + 1
+    return logits, {"blocks": new_blocks, "pos": new_pos}
 
 
 def prefill(params, cfg: ModelConfig, tokens, max_len: int, batch=None):

@@ -9,8 +9,9 @@ The step executor that turns every prior subsystem into a servable engine
    (constant / bursty / churn), quantized onto the step clock.
 2. **Model work** — PREFILL slots consume up to ``prefill_chunk`` prompt
    tokens (chunked prefill: long prompts never stall in-flight decode);
-   DECODE slots emit one token. Every produced K/V lands in the cold paged
-   pool at its request's allocator-assigned page (incremental page growth).
+   DECODE slots emit one token each, all from one batched executor call.
+   Every produced K/V lands in the cold paged pool at its request's
+   allocator-assigned page (incremental page growth).
 3. **Data path** — written pages are invalidated in every stream's hot
    tier (write coherence, §6), then all decoding slots sweep their context
    pages through the Leap-managed hot pools in one
@@ -27,15 +28,18 @@ The step executor that turns every prior subsystem into a servable engine
 Every phase of a step is annotated on the engine's
 :class:`repro.obs.metrics.Registry` (prefix ``engine.``): while a profiler
 trace is recording, it lands on the trace's clock beside the device ops.
-``engine.step`` holds ``admit``; per slot ``prefill`` or ``decode``
-(around the executor call) and ``kv_write`` (the cold-pool scatter), each
-with ``req_id``; ``lifecycle`` (the §12 mirror); ``invalidate``;
+``engine.step`` holds ``admit``; ``decode`` (the one executor call for
+every decoding slot, with ``rows``); per slot ``prefill`` (around the
+executor call) and ``kv_write`` (the cold-pool scatter), each with
+``req_id``; ``lifecycle`` (the §12 mirror); ``invalidate``;
 ``sweep_inputs``, ``tiered_sweep``, ``attention`` and ``pin`` (the flat
 reference and the host compare); and per finisher ``evict``. Only two are
 also histograms, the ones a report reads: ``tiered_sweep`` (the one span
-that syncs: its time includes the device's) and ``decode``. The request lifecycle is also exported as its own Perfetto
-track keyed by request id (slot-reuse-proof), next to the per-stream
-page-lifecycle tracks.
+that syncs: its time includes the device's) and ``decode``; beside them
+the ``decode_rows`` histogram counts the rows of each decode call. The
+request lifecycle is also exported as its own Perfetto track keyed by
+request id (slot-reuse-proof), next to the per-stream page-lifecycle
+tracks.
 """
 
 from __future__ import annotations
@@ -136,8 +140,9 @@ class ServingEngine:
 
     ``executor`` is a :class:`repro.serving.executor.ModelExecutor` or
     :class:`repro.serving.executor.SyntheticExecutor`; the engine only
-    assumes ``begin/end``, ``prefill_chunk``, ``decode`` and the
-    ``n_kv_heads / head_dim / dtype`` payload attributes.
+    assumes ``set_geometry`` (called once, here), ``begin/end``,
+    ``prefill_chunk``, ``decode_batch`` then ``decode`` per decoding
+    request, and the ``n_kv_heads / head_dim / dtype`` payload attributes.
     """
 
     def __init__(self, config: ServeConfig, executor, mesh=None):
@@ -174,6 +179,7 @@ class ServingEngine:
             Request(req_id=i, prompt_len=draw(c.prompt_len),
                     gen=draw(c.gen), arrival_step=int(arrivals[i]))
             for i in range(c.requests))
+        executor.set_geometry(c.slots, self.npps * c.page_size)
         self.dtype = jnp.dtype(executor.dtype)
         self.hq = getattr(executor, "n_q_heads", hkv)
         self.geom = TieredKV(n_pages, min(floor, n_pages), c.page_size,
@@ -336,7 +342,15 @@ class ServingEngine:
             written: list[tuple[int, int]] = []       # (slot, page)
             decoding: list[Request] = []
             finishers: list[Request] = []
-            for req in sorted(self.sched.active(), key=lambda r: r.slot):
+            active = sorted(self.sched.active(), key=lambda r: r.slot)
+            rows = [r for r in active if r.state == DECODE]
+            if rows:
+                # every decoding slot in one executor call; the slot loop
+                # below asks for each request's result
+                with self.reg.span("decode", rows=len(rows)):
+                    self.ex.decode_batch(rows)
+                self.reg.histogram("decode_rows").observe(len(rows))
+            for req in active:
                 if req.state == PREFILL:
                     n = min(self.cfg.prefill_chunk,
                             req.prompt_len - req.prefilled)
@@ -356,8 +370,7 @@ class ServingEngine:
                             finishers.append(req)
                 elif req.state == DECODE:
                     pos = req.prefilled + req.decoded - 1
-                    with self.reg.span("decode", req_id=req.req_id):
-                        k, v, tok = self.ex.decode(req)
+                    k, v, tok = self.ex.decode(req)
                     req.tokens.append(tok)
                     pages = self._write_tokens(req, k[None], v[None], pos)
                     written.extend((req.slot, p) for p in pages)
@@ -432,10 +445,13 @@ class ServingEngine:
 
     def _report(self, steps: int, wall: float) -> dict:
         """The run's summary. ``token_latency`` is the ladder of the
-        ``decode`` span: the host time of one slot's ``ex.decode`` call.
-        The span does not sync, so it holds the device's decode only where
-        the executor waits on it (``ModelExecutor``'s ``int(argmax)``);
-        with ``SyntheticExecutor`` it is the K/V draw's dispatch."""
+        ``decode`` span: the host time of a step's one
+        ``ex.decode_batch`` call over every decoding slot. The span does
+        not sync, so it holds the device's decode only where the executor
+        waits on it (``ModelExecutor`` reads the rows' greedy tokens back);
+        ``SyntheticExecutor`` stages nothing there (it draws each slot's
+        K/V in ``decode``), so its reading is the call's overhead alone.
+        ``mean_decode_rows`` is the mean number of rows per such call."""
         c = self.cfg
         totals = []
         for s in range(c.slots):
@@ -452,6 +468,7 @@ class ServingEngine:
         rnd = lambda d: {k: round(v, 5) if isinstance(v, float) else v
                          for k, v in d.items()}
         ttfts = self.reg.histogram("ttft_steps")
+        rows = self.reg.histogram("decode_rows")
         out = {
             "requests": c.requests,
             "slots": c.slots,
@@ -466,6 +483,8 @@ class ServingEngine:
             "mean_ttft_steps": round(float(np.mean(ttfts.samples)), 3)
             if ttfts.samples else float("nan"),
             "token_latency": rnd(self.reg.histogram("decode").ladder()),
+            "mean_decode_rows": round(float(np.mean(rows.samples)), 3)
+            if rows.samples else float("nan"),
             "pages_allocated": self.sched.pages_allocated,
             "pages_recycled": self.sched.pages_recycled,
             "alloc_in_use_end": self.allocator.in_use,

@@ -451,11 +451,12 @@ class TestEngineEndToEnd:
 PHASES = ("admit", "prefill", "decode", "kv_write", "invalidate",
           "sweep_inputs", "tiered_sweep", "attention", "pin", "evict")
 #: the phases opened once per request and step, tagged with its ``req_id``
-PER_REQUEST = ("prefill", "decode", "kv_write", "evict")
+PER_REQUEST = ("prefill", "kv_write", "evict")
 REPORT_KEYS = {"requests", "slots", "arrival", "admission", "steps",
                "wall_s", "tiered_equiv_ok", "requests_finished",
                "tokens_decoded", "ttft_steps", "mean_ttft_steps",
-               "token_latency", "pages_allocated", "pages_recycled",
+               "token_latency", "mean_decode_rows", "pages_allocated",
+               "pages_recycled",
                "alloc_in_use_end", "alloc_occupancy_peak",
                "prefetch_hits_total", "deferred_total"}
 
@@ -505,6 +506,11 @@ class TestEngineSpans:
         for phase in PER_REQUEST:
             ids = {e[3]["req_id"] for e in events if e[0] == f"engine.{phase}"}
             assert ids == set(range(5)), phase
+        # one decode span per step with decoding slots, tagged with their
+        # count: every decode of every request, once
+        rows = [e[3]["rows"] for e in events if e[0] == "engine.decode"]
+        assert all(1 <= n <= 2 for n in rows), rows
+        assert sum(rows) == sum(r.gen - 1 for r in eng.finished)
         assert not any(n.startswith("engine.") and n[7:] not in
                        want + ("step",) for n in names)
 
@@ -532,12 +538,17 @@ class TestEngineSpans:
 
     def test_only_spans_a_report_reads_keep_histograms(self):
         """Every other phase is a trace annotation alone: a run's registry
-        holds the sweep (``sweep_ms``), the decode call (``token_latency``)
-        and the TTFT steps, one sample per call and no more."""
+        holds the sweep (``sweep_ms``), the batched decode call
+        (``token_latency``) with its row count (``mean_decode_rows``) and
+        the TTFT steps, one sample per call and no more."""
         eng, report = _run_engine()
-        assert set(eng.reg._hists) == {"tiered_sweep", "decode", "ttft_steps"}
-        assert len(eng.reg.histogram("decode").samples) == sum(
-            r.gen - 1 for r in eng.finished)
+        assert set(eng.reg._hists) == {"tiered_sweep", "decode",
+                                       "decode_rows", "ttft_steps"}
+        rows = eng.reg.histogram("decode_rows").samples
+        assert len(eng.reg.histogram("decode").samples) == len(rows)
+        assert len(rows) == len(eng.reg.histogram("tiered_sweep").samples)
+        assert sum(rows) == sum(r.gen - 1 for r in eng.finished)
+        assert report["mean_decode_rows"] == round(float(np.mean(rows)), 3)
         assert len(eng.reg.histogram("ttft_steps").samples) == 5
 
     def test_request_stamps_are_ordered(self):
@@ -557,9 +568,11 @@ class TestEngineSpans:
         eng, report = _run_engine(trace=trace)
         extra = {"trace_totals_ok", "trace_events"} if trace else set()
         assert set(report) == REPORT_KEYS | extra
-        # one token latency per decode call
-        assert report["token_latency"]["n"] == sum(
-            r.gen - 1 for r in eng.finished)
+        # one token latency per batched decode call: one per step that
+        # decodes, the same steps that sweep
+        assert report["token_latency"]["n"] == len(
+            eng.reg.histogram("tiered_sweep").samples)
+        assert 1 <= report["mean_decode_rows"] <= eng.cfg.slots
 
 
 # --------------------------------------------------------------------------
